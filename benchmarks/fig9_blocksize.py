@@ -114,8 +114,9 @@ def run(rows=None):
     bench_ssm_chunks(rows)
     bench_chain_blocks(rows)
     bench_sort_chunks(rows)
-    # seed the runtime autotuner: the sweep's fastest tile/chunk become the
-    # serving defaults (ServiceConfig.tuned() reads them back).
+    # seed the runtime autotuner: the sweep's fastest tile/chunk become
+    # knobs a service opts into (ServiceConfig.tuned(Autotuner()) and
+    # KernelService(tuner=Autotuner()) read them back).
     try:
         from repro.runtime.autotune import seed_from_fig9
         best = seed_from_fig9(rows)

@@ -55,4 +55,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.configure()
     raise SystemExit(main())

@@ -88,6 +88,8 @@ def demo_lm_step():
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.configure()
     demo_scan1d()
     demo_chain()
     demo_dtw()

@@ -28,6 +28,11 @@ baseline-vs-Squire comparison (Fig. 8):
   * ``squire``   — chunk-parallel sort, fission/blocked chain, tiled
     wavefront SW (the accelerated configuration).
 Both modes are exact: results agree anchor-for-anchor and score-for-score.
+
+The platform picks the squire stages' implementation (kernels.mode): on a
+TPU chain and SW run the Pallas kernels (and the seed sort the rank
+kernel, inside core.sort); on the CPU they run the jnp stages. Both give
+the same anchors, chains and scores.
 """
 
 from __future__ import annotations
@@ -45,6 +50,7 @@ from repro.core import align as align_lib
 from repro.core import chain as chain_lib
 from repro.core import seeding
 from repro.core.chain import ChainParams
+from repro.kernels import mode as kmode
 from repro.runtime import bucketing
 from repro.runtime.dispatch import Dispatcher
 
@@ -60,7 +66,6 @@ class MapperConfig:
     sw_params: align_lib.SWParams = align_lib.SWParams()
     num_workers: int = 8        # sort chunks / chain blocks knob
     mode: str = "squire"        # squire | baseline
-    use_pallas: bool = False    # route SW/chain through the Pallas kernels
     read_bucket: int = 256      # reads padded to multiples of this
     anchor_bucket: int = 512    # anchor arrays padded to multiples of this
     sw_tile: int = 64           # wavefront tile (squire mode)
@@ -158,16 +163,32 @@ def _chain_fn_pallas(T: int):
     return run
 
 
+def chain_stage(mode: str, T: int):
+    """The chain stage fn for ``mode`` on this platform: the Pallas chain
+    kernel for squire on a TPU, else the jnp schedule (blocked for
+    squire, sequential for baseline)."""
+    if mode == "squire" and kmode.use_kernels():
+        return _chain_fn_pallas(T)
+    return _chain_fn(T, "blocked" if mode == "squire" else "sequential", 16)
+
+
+def sw_stage(mode: str, tile: int, params: align_lib.SWParams):
+    """The align stage fn for ``mode`` on this platform (see _sw_fn)."""
+    kernels = mode == "squire" and kmode.use_kernels()
+    return _sw_fn(mode, tile, kernels, params)
+
+
 @functools.lru_cache(maxsize=None)
-def _sw_fn(mode: str, tile: int, use_pallas: bool,
+def _sw_fn(mode: str, tile: int, kernels: bool,
            params: align_lib.SWParams):
     """-> (fn(a, b) -> (mat, score), whole_jit: bool).
 
     ``whole_jit=False`` marks eager wavefront schedules (only the tile is
     jitted — tracing the whole matrix would unroll thousands of tiles);
-    the Dispatcher passes such fns through un-jitted.
+    the Dispatcher passes such fns through un-jitted. ``kernels`` (the
+    platform's choice, part of the cache key) takes the Pallas tile.
     """
-    if use_pallas:
+    if kernels:
         from repro.kernels import ops
         fn = ops.make_sw_tile_fn(params.match, params.mismatch, params.gap)
 
@@ -213,16 +234,9 @@ class ReadMapper:
         cfg = self.cfg
         nv = len(q)
         qp, rp, vp = chain_payload(q, r, cfg)
-        if cfg.use_pallas:
-            f, pred = self.runtime.run_one(
-                _chain_fn_pallas(cfg.band_T),
-                (jnp.asarray(qp), jnp.asarray(rp), jnp.asarray(vp)),
-                jit=False)
-        else:
-            mode = "blocked" if cfg.mode == "squire" else "sequential"
-            f, pred = self.runtime.run_one(
-                _chain_fn(cfg.band_T, mode, 16),
-                (jnp.asarray(qp), jnp.asarray(rp), jnp.asarray(vp)))
+        f, pred = self.runtime.run_one(
+            chain_stage(cfg.mode, cfg.band_T),
+            (jnp.asarray(qp), jnp.asarray(rp), jnp.asarray(vp)))
         return np.asarray(f)[:nv], np.asarray(pred)[:nv]
 
     def _align(self, read: np.ndarray, ref_lo: int, ref_hi: int
@@ -230,8 +244,7 @@ class ReadMapper:
         cfg = self.cfg
         window = self.reference[ref_lo:ref_hi].astype(np.int32)
         a, b = align_payload(read, window, cfg)
-        fn, whole_jit = _sw_fn(cfg.mode, cfg.sw_tile, cfg.use_pallas,
-                               cfg.sw_params)
+        fn, whole_jit = sw_stage(cfg.mode, cfg.sw_tile, cfg.sw_params)
         mat, score = self.runtime.run_one(
             fn, (jnp.asarray(a), jnp.asarray(b)), jit=whole_jit)
         end_i, end_j = align_lib.sw_end_position(mat)

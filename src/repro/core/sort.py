@@ -15,10 +15,15 @@ TPU adaptation:
 
 Supports an optional value array (sort-by-key), which seeding/chaining use
 to carry query positions alongside reference positions.
+
+On a TPU the chunk sort runs the Pallas rank kernel
+(kernels.ops.radix_sort_chunks); the jnp counting pass below is the CPU
+path and the oracle. Both are stable, so the result is the same.
 """
 
 from __future__ import annotations
 
+import math
 from functools import partial
 from typing import Optional, Tuple
 
@@ -29,6 +34,7 @@ Array = jnp.ndarray
 
 RADIX_BITS = 8
 RADIX = 1 << RADIX_BITS
+_LANES = 128       # the rank kernel's key block is lane-aligned
 
 
 def _counting_pass(keys: Array, vals: Array, shift: int) -> Tuple[Array, Array]:
@@ -76,14 +82,21 @@ def radix_sort(keys: Array, vals: Optional[Array] = None,
     worker path and sort in one chunk — chunking overhead dominates below
     ~10k elements on Squire, and below one tile here.
     """
+    from repro.kernels import mode
+
     if vals is None:
         vals = jnp.arange(keys.shape[0], dtype=jnp.int32)
     n = keys.shape[0]
-    if n < min_parallel or num_chunks == 1:
+    kernels = mode.use_kernels()
+    if n < min_parallel:
+        num_chunks = 1
+    if num_chunks == 1 and not kernels:
         return radix_sort_chunk(keys, vals, key_bits)
 
-    # pad to a multiple of num_chunks with +inf-like keys (sort to the end)
-    pad = (-n) % num_chunks
+    # pad to a multiple of num_chunks (x the kernel's 128-lane key block)
+    # with +inf-like keys: stable, so they sort after every real key and
+    # the slice below drops exactly them
+    pad = (-n) % (num_chunks * (_LANES if kernels else 1))
     maxk = jnp.array(jnp.iinfo(keys.dtype).max, keys.dtype)
     if pad:
         keys = jnp.concatenate([keys, jnp.full((pad,), maxk, keys.dtype)])
@@ -92,7 +105,13 @@ def radix_sort(keys: Array, vals: Optional[Array] = None,
 
     kc = keys.reshape(num_chunks, lc)
     vc = vals.reshape(num_chunks, lc)
-    kc, vc = jax.vmap(partial(radix_sort_chunk, key_bits=key_bits))(kc, vc)
+    if kernels:
+        from repro.kernels import ops
+        kc, vc = ops.radix_sort_chunks(kc, vc, key_bits=key_bits,
+                                       block=math.gcd(lc, 512))
+    else:
+        kc, vc = jax.vmap(partial(radix_sort_chunk, key_bits=key_bits))(
+            kc, vc)
 
     # log2 rounds of pairwise merges
     chunks = [(kc[i], vc[i]) for i in range(num_chunks)]

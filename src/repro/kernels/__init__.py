@@ -9,7 +9,8 @@ radix_rank       — radix counting-sort rank/histogram pass (Alg. 1 hot-spot)
 
 ops.py: jit'd wrappers (padding, layout, wavefront/sort integration).
 ref.py: pure-jnp oracles; tests assert allclose across shape/dtype sweeps.
-All kernels run under interpret=True on CPU; compiled mode on real TPUs.
+mode.py: the kernel mode from the platform — compiled through Mosaic on a
+TPU, the Pallas interpreter on the CPU; no wrapper takes it as an option.
 """
 
 from repro.kernels import ops, ref  # noqa: F401

@@ -4,8 +4,7 @@ One program computes a (tr x tc) DP tile given its top row, left column and
 corner boundary values — the exact unit Squire's workers compute between
 local-counter handoffs (Alg. 4 / Fig. 5). Inside the tile, cells are swept
 in anti-diagonal order with the whole diagonal updated as one vector op
-(the fine-grain parallelism; tr lanes), using two rolling diagonal buffers
-in VMEM.
+(the fine-grain parallelism; one lane per tile row).
 
 Output is *diagonal-major*: D[k, i] = M[i, k - i]. ops.py converts back to
 row-major and extracts boundaries (a production kernel would emit tiles
@@ -19,8 +18,17 @@ Dependency bookkeeping (i = row lane, j = k - i):
                      = left[i-1]    if j == 0
                      = D_{k-2}[i-1] otherwise
 
-VMEM: 2 diagonal buffers (tr,) + boundaries + the (K, tr) output block;
-tr = tc = 128 -> ~140 KB fp32.
+Layout (what Mosaic accepts): rows live on the lanes of (1, L) vectors,
+L = max(tr, tc) rounded up to 128 (padding lanes sit above the real rows
+and never feed them). "x[i-1]" is a one-lane roll. The per-column inputs
+top[k - i] and b[k - i] are shift registers: each diagonal rolls them one
+lane up and takes the entering element top[k] / b[k] from lane 0 of a
+source row that rolls one lane down. So the kernel never reverses or
+slices a vector at a dynamic offset, and every operand is a (1, L) VMEM
+row (batchable under vmap: the service's batched tiles). Diagonals are
+produced 8 at a time into one (8, L) tile and stored at an aligned row
+offset. VMEM: the (K, L) output block plus a few vregs; tr = tc = 128 ->
+~130 KB fp32.
 """
 
 from __future__ import annotations
@@ -32,86 +40,89 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mode
+
 BIG = 1e18  # python float: compile-time immediate inside the kernel
+LANES = 128
 
 
-def _rev_gather(x_rev_padded, xlen: int, tr: int, k2):
-    """val[i] = x[k2 - i] for i in [0, tr); junk where out of range."""
-    start = xlen - 1 - k2 + tr
-    return jax.lax.dynamic_slice(x_rev_padded, (start,), (tr,))
-
-
-def _dp_tile_kernel(top_ref, left_ref, corner_ref, a_ref, b_ref, d_ref,
-                    d1_ref, d2_ref, *, kind: str, tr: int, tc: int,
-                    match: float, mismatch: float, gap: float):
-    top = top_ref[...]
+def _dp_tile_kernel(corner_ref, top_ref, b_ref, left_ref, a_ref, d_ref, *,
+                    kind: str, tc: int, match: float, mismatch: float,
+                    gap: float):
+    width = a_ref.shape[1]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, width), 0)
     left = left_ref[...]
-    corner = corner_ref[0]
-    a = a_ref[...].astype(jnp.float32)
-    b = b_ref[...].astype(jnp.float32)
+    a = a_ref[...]
+    corner = corner_ref[...]                      # lane 0 holds the corner
 
-    zpad = jnp.zeros((tr,), jnp.float32)
-    top_rp = jnp.concatenate([zpad, top[::-1], zpad])
-    b_rp = jnp.concatenate([zpad, b[::-1], zpad])
+    def prev(x):                                  # lane i <- x[i-1]
+        return pltpu.roll(x, 1, 1)
 
-    ii = jnp.arange(tr)
-    left_shift = jnp.concatenate([left[:1], left[:-1]])  # left[i-1]
+    def next_(x):                                 # lane i <- x[i+1]
+        return pltpu.roll(x, width - 1, 1)
 
-    def step(k, _):
-        jj = k - ii
-        valid = (jj >= 0) & (jj < tc)
-        d1 = d1_ref[...]
-        d2 = d2_ref[...]
-        d1s = jnp.concatenate([d1[:1], d1[:-1]])          # D_{k-1}[i-1]
-        d2s = jnp.concatenate([d2[:1], d2[:-1]])          # D_{k-2}[i-1]
-        topj = _rev_gather(top_rp, tc, tr, k)
-        topjm1 = _rev_gather(top_rp, tc, tr, k - 1)
-        bj = _rev_gather(b_rp, tc, tr, k)
+    left_shift = prev(left)                       # left[i-1]
+    pad_val = BIG if kind == "dtw" else 0.0
 
-        up = jnp.where(ii == 0, topj, d1s)
-        lf = jnp.where(jj == 0, left, d1)
-        dg = jnp.where(ii == 0, topjm1,
-                       jnp.where(jj == 0, left_shift, d2s))
-        dg = jnp.where((ii == 0) & (jj == 0), corner, dg)
+    def group(g, carry):
+        d1, d2, topjm1, bjm1, top_src, b_src = carry
+        out = jnp.zeros((8, width), jnp.float32)
+        for r in range(8):
+            k = g * 8 + r
+            # lane 0 of *_src is top[k] / b[k] (junk past tc: masked)
+            topj = jnp.where(ii == 0, top_src, prev(topjm1))      # top[k-i]
+            bj = jnp.where(ii == 0, b_src, prev(bjm1))            # b[k-i]
+            jj = k - ii
+            valid = (jj >= 0) & (jj < tc)
+            up = jnp.where(ii == 0, topj, prev(d1))
+            lf = jnp.where(jj == 0, left, d1)
+            dg = jnp.where(ii == 0, topjm1,
+                           jnp.where(jj == 0, left_shift, prev(d2)))
+            dg = jnp.where((ii == 0) & (jj == 0), corner, dg)
 
-        if kind == "dtw":
-            new = jnp.abs(a - bj) + jnp.minimum(dg, jnp.minimum(up, lf))
-            pad_val = BIG
-        elif kind == "sw":
-            sub = jnp.where(a == bj, jnp.float32(match),
-                            jnp.float32(mismatch))
-            new = jnp.maximum(dg + sub,
-                              jnp.maximum(up - gap, lf - gap))
-            new = jnp.maximum(new, 0.0)
-            pad_val = jnp.float32(0.0)
-        else:
-            raise ValueError(kind)
+            if kind == "dtw":
+                new = jnp.abs(a - bj) + jnp.minimum(dg, jnp.minimum(up, lf))
+            elif kind == "sw":
+                s = jnp.where(a == bj, jnp.float32(match),
+                              jnp.float32(mismatch))
+                new = jnp.maximum(dg + s, jnp.maximum(up - gap, lf - gap))
+                new = jnp.maximum(new, 0.0)
+            else:
+                raise ValueError(kind)
 
-        new = jnp.where(valid, new, pad_val)
-        d_ref[pl.ds(k, 1), :] = new[None, :]
-        d2_ref[...] = d1
-        d1_ref[...] = new
-        return 0
+            new = jnp.where(valid, new, pad_val)
+            out = jnp.where(sub == r, new, out)
+            d1, d2, topjm1, bjm1 = new, d1, topj, bj
+            top_src, b_src = next_(top_src), next_(b_src)
+        d_ref[pl.ds(pl.multiple_of(g * 8, 8), 8), :] = out
+        return d1, d2, topjm1, bjm1, top_src, b_src
 
-    jax.lax.fori_loop(0, tr + tc - 1, step, 0, unroll=False)
+    zero = jnp.zeros((1, width), jnp.float32)
+    jax.lax.fori_loop(0, d_ref.shape[0] // 8, group,
+                      (zero, zero, zero, zero, top_ref[...], b_ref[...]))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("kind", "match", "mismatch", "gap",
-                                    "interpret"))
+                   static_argnames=("kind", "match", "mismatch", "gap"))
 def dp_tile_pallas(top, left, corner, a, b, *, kind: str = "dtw",
                    match: float = 2.0, mismatch: float = -4.0,
-                   gap: float = 4.0, interpret: bool = True):
+                   gap: float = 4.0):
     """Run one wavefront tile. Returns diagonal-major D (tr+tc-1, tr)."""
     tr, tc = a.shape[0], b.shape[0]
-    kern = functools.partial(_dp_tile_kernel, kind=kind, tr=tr, tc=tc,
+    n_diag = tr + tc - 1
+    width = -(-max(tr, tc) // LANES) * LANES
+    rows = -(-n_diag // 8) * 8
+
+    def row(x):
+        x = jnp.reshape(x, (1, -1)).astype(jnp.float32)
+        return jnp.pad(x, ((0, 0), (0, width - x.shape[1])))
+
+    kern = functools.partial(_dp_tile_kernel, kind=kind, tc=tc,
                              match=match, mismatch=mismatch, gap=gap)
-    return pl.pallas_call(
+    d = pl.pallas_call(
         kern,
-        out_shape=jax.ShapeDtypeStruct((tr + tc - 1, tr), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((tr,), jnp.float32),
-                        pltpu.VMEM((tr,), jnp.float32)],
-        interpret=interpret,
-    )(top.astype(jnp.float32), left.astype(jnp.float32),
-      jnp.atleast_1d(corner).astype(jnp.float32),
-      a.astype(jnp.float32), b.astype(jnp.float32))
+        out_shape=jax.ShapeDtypeStruct((rows, width), jnp.float32),
+        interpret=mode.interpret(),
+    )(row(corner), row(top), row(b), row(left), row(a))
+    return d[:n_diag, :tr]

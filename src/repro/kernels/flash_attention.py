@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mode
+
 NEG_INF = -1e30
 
 
@@ -78,10 +80,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("window", "bq", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("window", "bq", "bk"))
 def flash_attention_pallas(q, k, v, *, window: int = 0, bq: int = 128,
-                           bk: int = 128, interpret: bool = True):
+                           bk: int = 128):
     """Fused causal (optionally sliding-window) attention.
 
     q: (B, H, Sq, hd); k, v: (B, KV, Skv, hd) with H % KV == 0.
@@ -115,5 +116,5 @@ def flash_attention_pallas(q, k, v, *, window: int = 0, bq: int = 128,
         scratch_shapes=[pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, hd), jnp.float32)],
-        interpret=interpret,
+        interpret=mode.interpret(),
     )(q, k, v)

@@ -5,8 +5,8 @@ layouts back to caller layouts, and plug the tile kernel into the
 core.wavefront scheduler so `dtw_tiled(..., tile_fn=ops.dtw_tile_fn)` runs
 the Pallas path end to end.
 
-`interpret=True` everywhere in this repo: the container is CPU-only; on a
-real TPU these flip to compiled mode unchanged.
+The kernel mode (compiled on a TPU, interpreted on the CPU) comes from the
+platform (kernels.mode); no wrapper takes it as an argument.
 """
 
 from __future__ import annotations
@@ -27,7 +27,7 @@ NEG = jnp.float32(-1e18)
 # ssm_scan
 # --------------------------------------------------------------------------
 
-def ssm_scan(r, w, k, v, u=None, chunk: int = 64, interpret: bool = True):
+def ssm_scan(r, w, k, v, u=None, chunk: int = 64):
     """Chunked WKV scan with automatic T-padding. Shapes (B, T, d*)."""
     b, t, dk = r.shape
     if u is None:
@@ -40,7 +40,7 @@ def ssm_scan(r, w, k, v, u=None, chunk: int = 64, interpret: bool = True):
         w = jnp.concatenate([w, jnp.ones((b, pad, dk), w.dtype)], axis=1)
         k = jnp.concatenate([k, padk], axis=1)
         v = jnp.concatenate([v, padv], axis=1)
-    y = ssm_scan_pallas(r, w, k, v, u, chunk=chunk, interpret=interpret)
+    y = ssm_scan_pallas(r, w, k, v, u, chunk=chunk)
     return y[:, :t]
 
 
@@ -48,8 +48,7 @@ def ssm_scan(r, w, k, v, u=None, chunk: int = 64, interpret: bool = True):
 # chain
 # --------------------------------------------------------------------------
 
-def chain_scan(scores, w, block: int = 256, lanes: int = 128,
-               interpret: bool = True):
+def chain_scan(scores, w, block: int = 1024, lanes: int = 128):
     """Banded chain recurrence with band padded to `lanes` and N to block."""
     n, t = scores.shape
     if t < lanes:
@@ -60,20 +59,19 @@ def chain_scan(scores, w, block: int = 256, lanes: int = 128,
         scores = jnp.concatenate(
             [scores, jnp.full((padn, scores.shape[1]), NEG)], axis=0)
         w = jnp.concatenate([w, jnp.full((padn,), NEG)], axis=0)
-    f, off = chain_scan_pallas(scores, w, block=block, interpret=interpret)
+    f, off = chain_scan_pallas(scores, w, block=block)
     off = jnp.minimum(off, t)  # padded lanes can never win, but clamp anyway
     return f[:n], off[:n]
 
 
-def chain_anchors(q, r, T: int = 64, params=None, block: int = 256,
-                  interpret: bool = True):
+def chain_anchors(q, r, T: int = 64, params=None, block: int = 1024):
     """Drop-in for core.chain.chain_anchors on the Pallas path."""
     from repro.core import chain as cchain
     params = params or cchain.ChainParams()
     n = q.shape[0]
     w = jnp.full((n,), float(params.kmer), jnp.float32)
     scores = cchain.chain_scores(q, r, T, params)   # fission phase (dense)
-    f, off = chain_scan(scores, w, block=block, interpret=interpret)
+    f, off = chain_scan(scores, w, block=block)
     pred = jnp.where(off > 0, jnp.arange(n) - off, -1)
     return f, pred
 
@@ -88,11 +86,12 @@ def _diag_to_row_major(d, tr: int, tc: int):
     return d[rows + cols, jnp.broadcast_to(rows, (tr, tc))]
 
 
-def dp_tile(top, left, corner, a, b, *, kind="dtw", interpret=True, **params):
+@functools.partial(jax.jit,
+                   static_argnames=("kind", "match", "mismatch", "gap"))
+def dp_tile(top, left, corner, a, b, *, kind="dtw", **params):
     """Pallas tile with core.wavefront.TileFn signature."""
     tr, tc = a.shape[0], b.shape[0]
-    d = dp_tile_pallas(top, left, corner, a, b, kind=kind,
-                       interpret=interpret, **params)
+    d = dp_tile_pallas(top, left, corner, a, b, kind=kind, **params)
     tile = _diag_to_row_major(d, tr, tc)
     return tile, tile[-1, :], tile[:, -1], tile[-1, -1]
 
@@ -111,7 +110,7 @@ def make_sw_tile_fn(match=2.0, mismatch=-4.0, gap=4.0):
 # --------------------------------------------------------------------------
 
 def radix_sort_chunks(keys, vals=None, key_bits: int = 32,
-                      block: int = 512, interpret: bool = True):
+                      block: int = 512):
     """Chunk-parallel LSD radix sort on the Pallas rank kernel.
 
     keys: (n_chunks, chunk_len) uint32 -> sorted within each chunk; the
@@ -125,8 +124,7 @@ def radix_sort_chunks(keys, vals=None, key_bits: int = 32,
                                 keys.shape)
     blk = min(block, clen)
     for shift in range(0, key_bits, 8):
-        ranks, hists = radix_rank_pallas(keys, shift=shift, block=blk,
-                                         interpret=interpret)
+        ranks, hists = radix_rank_pallas(keys, shift=shift, block=blk)
         starts = jnp.cumsum(hists, axis=1) - hists          # exclusive
         bucket = ((keys >> shift) & 255).astype(jnp.int32)
         pos = jnp.take_along_axis(starts, bucket, axis=1) + ranks

@@ -3,19 +3,24 @@
 One LSD radix pass over a worker chunk = three dependency-bound steps:
 per-key bucket histogram, exclusive bucket prefix (the serial part), and
 stable rank assignment. The paper's workers run this scalar loop per
-chunk; the TPU version keeps the (C, R) one-hot block in VMEM and turns
+chunk; the TPU version keeps the (R, C) one-hot block in VMEM and turns
 the histogram + stable rank into MXU/VPU work:
 
   * grid = chunks ("workers") — dependency-free fine-grain parallelism,
   * per chunk: bucket = (keys >> shift) & (R-1); the running per-bucket
-    count is a VMEM carry across C-sized key blocks (the global-counter
+    count is a (R, 1) carry across C-sized key blocks (the global-counter
     pattern — order inside a chunk preserves stability),
-  * rank[i] = running_count[bucket_i] before i, computed blockwise with a
-    causal one-hot cumsum (vectorized, C x R in VMEM).
+  * rank[i] = running_count[bucket_i] before i, computed blockwise: the
+    causal prefix count of the (R, C) one-hot along the keys is one MXU
+    product with a strictly upper-triangular (C, C) ones matrix (0/1
+    operands in bf16, fp32 accumulation — exact up to 2^24 keys).
 
-Output is each key's stable rank within (chunk, bucket) plus the chunk's
-bucket histogram; ops.py composes ranks + histograms into scatter
-positions exactly like core.sort._counting_pass (the jnp oracle).
+Layout (what Mosaic accepts): keys are (n_chunks, 1, chunk_len) so every
+block's last two dims equal the array's; buckets run along sublanes and
+keys along lanes, so no transpose is needed. Output is each key's stable
+rank within (chunk, bucket) plus the chunk's bucket histogram; ops.py
+composes ranks + histograms into scatter positions exactly like
+core.sort._counting_pass (the jnp oracle).
 """
 
 from __future__ import annotations
@@ -25,41 +30,40 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from repro.kernels import mode
 
 
-def _rank_kernel(keys_ref, rank_ref, hist_ref, count_ref, *,
-                 block: int, n_blocks: int, radix: int, shift: int):
-    @pl.when(pl.program_id(0) >= 0)      # init per chunk (grid dim 0)
-    def _init():
-        count_ref[...] = jnp.zeros_like(count_ref)
+def _rank_kernel(keys_ref, rank_ref, hist_ref, *, block: int, n_blocks: int,
+                 radix: int, shift: int):
+    buckets = jax.lax.broadcasted_iota(jnp.int32, (radix, 1), 0)
+    src = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    before = (src < dst).astype(jnp.bfloat16)     # key c' precedes key c
 
-    def body(i, _):
-        keys = keys_ref[0, pl.ds(i * block, block)]          # (C,)
-        bucket = (keys >> shift) & (radix - 1)
-        onehot = (bucket[:, None] ==
-                  jax.lax.iota(jnp.uint32, radix)[None, :])  # (C, R) bool
-        oh = onehot.astype(jnp.int32)
+    def body(i, count):
+        off = pl.multiple_of(i * block, block)
+        keys = keys_ref[0, :, pl.ds(off, block)]                  # (1, C)
+        bucket = jax.lax.shift_right_logical(keys, shift) & (radix - 1)
+        onehot = (bucket == buckets).astype(jnp.int32)            # (R, C)
         # stable rank: keys earlier in the block with the same bucket
-        within = jnp.cumsum(oh, axis=0) - oh                 # (C, R)
-        prior = count_ref[...]                               # (1, R)
-        rank = jnp.sum((within + prior) * oh, axis=1)        # (C,)
-        rank_ref[0, pl.ds(i * block, block)] = rank.astype(jnp.int32)
-        count_ref[...] = prior + jnp.sum(oh, axis=0)[None, :]
-        return 0
+        within = jax.lax.dot_general(
+            onehot.astype(jnp.bfloat16), before, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32).astype(jnp.int32)
+        rank = jnp.sum((within + count) * onehot, axis=0, keepdims=True)
+        rank_ref[0, :, pl.ds(off, block)] = rank
+        return count + jnp.sum(onehot, axis=1, keepdims=True)     # (R, 1)
 
-    jax.lax.fori_loop(0, n_blocks, body, 0, unroll=False)
-    hist_ref[0, :] = count_ref[0, :]
+    hist_ref[0] = jax.lax.fori_loop(0, n_blocks, body,
+                                    jnp.zeros((radix, 1), jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("radix", "shift", "block",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("radix", "shift", "block"))
 def radix_rank_pallas(keys, *, radix: int = 256, shift: int = 0,
-                      block: int = 512, interpret: bool = True):
+                      block: int = 512):
     """keys: (n_chunks, chunk_len) uint32. Returns (ranks, hists):
     ranks (n_chunks, chunk_len) int32 — stable rank within (chunk, bucket);
     hists (n_chunks, radix) int32 — per-chunk bucket histogram.
-    chunk_len must be a multiple of `block`.
+    chunk_len must be a multiple of `block` (and `block` of 128 on chip).
     """
     n_chunks, clen = keys.shape
     if clen % block:
@@ -67,14 +71,15 @@ def radix_rank_pallas(keys, *, radix: int = 256, shift: int = 0,
     kern = functools.partial(_rank_kernel, block=block,
                              n_blocks=clen // block, radix=radix,
                              shift=shift)
-    return pl.pallas_call(
+    row = pl.BlockSpec((1, 1, clen), lambda c: (c, 0, 0))
+    ranks, hists = pl.pallas_call(
         kern,
         grid=(n_chunks,),
-        in_specs=[pl.BlockSpec((1, clen), lambda c: (c, 0))],
-        out_specs=[pl.BlockSpec((1, clen), lambda c: (c, 0)),
-                   pl.BlockSpec((1, radix), lambda c: (c, 0))],
-        out_shape=[jax.ShapeDtypeStruct((n_chunks, clen), jnp.int32),
-                   jax.ShapeDtypeStruct((n_chunks, radix), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((1, radix), jnp.int32)],
-        interpret=interpret,
-    )(keys.astype(jnp.uint32))
+        in_specs=[row],
+        out_specs=[row, pl.BlockSpec((1, radix, 1), lambda c: (c, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n_chunks, 1, clen), jnp.int32),
+                   jax.ShapeDtypeStruct((n_chunks, radix, 1), jnp.int32)],
+        interpret=mode.interpret(),
+    )(jax.lax.bitcast_convert_type(keys.astype(jnp.uint32),
+                                   jnp.int32)[:, None, :])
+    return ranks[:, 0, :], hists[:, :, 0]

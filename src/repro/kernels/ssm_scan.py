@@ -28,6 +28,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import mode
+
 
 def _ssm_kernel(r_ref, w_ref, k_ref, v_ref, u_ref, y_ref, state_ref,
                 *, chunk: int):
@@ -54,9 +56,8 @@ def _ssm_kernel(r_ref, w_ref, k_ref, v_ref, u_ref, y_ref, state_ref,
     jax.lax.fori_loop(0, chunk, step, 0, unroll=False)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssm_scan_pallas(r, w, k, v, u, *, chunk: int = 64,
-                    interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def ssm_scan_pallas(r, w, k, v, u, *, chunk: int = 64):
     """Chunked WKV-style scan.
 
     Args:
@@ -85,5 +86,5 @@ def ssm_scan_pallas(r, w, k, v, u, *, chunk: int = 64,
         out_specs=blk(dv),
         out_shape=jax.ShapeDtypeStruct((b, t, dv), jnp.float32),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=mode.interpret(),
     )(f32(r), f32(w), f32(k), f32(v), f32(u))

@@ -19,7 +19,7 @@ from typing import Optional
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def make_worker_mesh(num_workers: Optional[int] = None,
@@ -48,14 +48,21 @@ def make_worker_mesh(num_workers: Optional[int] = None,
     return Mesh(np.asarray(devs[:n]), (axis,))
 
 
+def auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with Auto axes: the installed jax defaults to
+    Explicit axes, under which ``with_sharding_constraint`` (the
+    sharding.partition rules) and the sharded gathers are refused."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_smoke_mesh(*, multi_pod: bool = False):
     """Tiny mesh for CI-style tests (8 forced host devices)."""
     shape = (2, 2, 2) if multi_pod else (2, 4)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)

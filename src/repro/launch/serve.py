@@ -1,9 +1,9 @@
 """Serving launcher: batched prefill + decode over static-shape caches.
 
-Runs a reduced (or full, on real hardware) config through the serve
-engine: a batch of prompts is prefilled once, then decoded token-by-token
-— the decode loop is the 1-D dependency-bound recurrence of serving
-(DESIGN.md: the global-counter pattern at request scale). SSM/hybrid archs
+Runs the published config (or, with --reduced, the smoke-scale one on the
+CPU) through the serve engine: a batch of prompts is prefilled once, then
+decoded token-by-token — the decode loop is the 1-D dependency-bound
+recurrence of serving (the global-counter pattern at request scale). SSM/hybrid archs
 decode with O(1) state; attention archs with ring-buffer KV caches.
 
 Usage:
@@ -20,8 +20,8 @@ import time
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-1.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--reduced", action="store_true",
+                    help="use the smoke-scale config (CPU-sized)")
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
@@ -30,6 +30,9 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+
+    from repro.launch import compile_cache
+    compile_cache.configure()
 
     import jax
     import jax.numpy as jnp

@@ -47,6 +47,8 @@ def main(argv=None):
             "XLA_FLAGS", "--xla_force_host_platform_device_count=512")
 
     # import after XLA_FLAGS so the device count sticks
+    from repro.launch import compile_cache
+    compile_cache.configure()
     from repro import configs
     from repro.data.lm import DataConfig, TokenStream
     from repro.launch import mesh as mesh_lib

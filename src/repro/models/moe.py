@@ -34,11 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:                                    # jax >= 0.6 re-exports at top level
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.models import layers as L
 from repro.sharding import current_mesh, logical, shard_act
 from repro.sharding.partition import param_spec
@@ -207,7 +202,7 @@ def _moe_shard_map(params, cfg: MoEConfig, x: Array, mesh, m: int):
             y = jax.lax.pmean(y, unused_axes)
         return y, aux
 
-    wrapped = _shard_map(
+    wrapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(router_spec, wg_spec, wg_spec, wg_spec, x_spec),
         out_specs=(x_spec, P()))

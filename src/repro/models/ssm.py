@@ -34,6 +34,13 @@ Array = jnp.ndarray
 # RWKV6 time mix
 # ---------------------------------------------------------------------------
 
+# RWKV-6's ln_x: GroupNorm eps = 1e-5 * head_size_divisor**2 (divisor 8).
+# A head whose WKV readout is near rank-1 (the first positions) has a tiny
+# variance; the larger eps keeps its normalisation continuous instead of
+# amplifying rounding noise into a sign flip.
+LN_X_EPS = 64e-5
+
+
 class RWKVConfig(NamedTuple):
     d_model: int
     head_dim: int = 64
@@ -157,7 +164,8 @@ def rwkv_time_mix(params, cfg: RWKVConfig, x: Array,
 
     y = yf.transpose(0, 2, 1, 3).reshape(b, s, d)
     y = shard_act(y, "batch", "seq", None)
-    y = L.groupnorm(params["ln_x"], y.astype(dt), groups=h)
+    y = L.groupnorm(params["ln_x"], y.astype(dt), groups=h,
+                    eps=LN_X_EPS)
     y = (y * g) @ params["wo"].astype(dt)
     new_state = {"s": s_fin,
                  "x_prev": x[:, -1].astype(jnp.float32)}
@@ -192,7 +200,8 @@ def rwkv_time_mix_decode(params, cfg: RWKVConfig, x: Array, state: dict):
     yf = yf + bonus[:, None] * fold(v).astype(jnp.float32)
 
     y = yf.reshape(b, h * hd)[:, None, :]
-    y = L.groupnorm(params["ln_x"], y.astype(dt), groups=h)
+    y = L.groupnorm(params["ln_x"], y.astype(dt), groups=h,
+                    eps=LN_X_EPS)
     y = (y * g[:, None]) @ params["wo"].astype(dt)
     new_state = {"s": s_fin.reshape(b, h, hd, hd),
                  "x_prev": x[:, -1].astype(jnp.float32)}

@@ -40,11 +40,6 @@ from repro.obs import metrics as obs_metrics
 from repro.obs import sampler as obs_sampler
 from repro.obs import trace as obs_trace
 
-try:                                    # jax >= 0.6 re-exports at top level
-    _shard_map = jax.shard_map
-except AttributeError:                  # 0.4.x experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 # Canonical constructor lives in repro.launch.mesh (validates worker count
 # against available devices up front); re-exported here for callers that
@@ -69,8 +64,8 @@ def _jit_batched(fn, in_axes: Tuple, mesh: Optional[Mesh], axis):
     vfn = jax.vmap(fn, in_axes=in_axes)
     if mesh is not None:
         specs = tuple(P(axis) if ax == 0 else P() for ax in in_axes)
-        vfn = _shard_map(vfn, mesh=mesh, in_specs=specs,
-                         out_specs=P(axis))
+        vfn = jax.shard_map(vfn, mesh=mesh, in_specs=specs,
+                            out_specs=P(axis))
     return jax.jit(vfn)
 
 

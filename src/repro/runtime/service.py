@@ -44,6 +44,7 @@ from repro.core import sort as rsort
 from repro.core import wavefront
 from repro.core.scan1d import affine_scan
 from repro.core.semiring import SEMIRINGS, finite_zero
+from repro.kernels import mode as kmode
 from repro.obs import metrics as obs_metrics
 from repro.obs import sampler as obs_sampler
 from repro.runtime import bucketing
@@ -78,9 +79,11 @@ class ServiceConfig:
     # pipeline
     pipeline_depth: int = 2
 
-    def tuned(self, tuner: Optional[Autotuner] = None) -> "ServiceConfig":
-        """Override tile/chunk knobs from the autotune cache (fig9-seeded)."""
-        tuner = tuner or Autotuner()
+    def tuned(self, tuner: Autotuner) -> "ServiceConfig":
+        """Override tile/chunk knobs from an autotune cache (fig9-seeded).
+        The cache is always named by the caller: by default the service
+        compiles from these knobs alone, never from a file outside the
+        checkout."""
         over = {}
         dtw_tile = tuner.get("dtw.tile")
         if dtw_tile:
@@ -147,13 +150,22 @@ def _sort_fn(num_chunks: int):
 
 
 @functools.lru_cache(maxsize=None)
-def _sw_tile_batched(params: align_lib.SWParams):
-    return jax.jit(jax.vmap(functools.partial(align_lib._sw_tile_fn,
-                                              params)))
+def _sw_tile_batched(params: align_lib.SWParams, kernels: bool):
+    """Batched SW tile: the Pallas tile where ``kernels`` (the platform's
+    choice, kernels.mode.use_kernels), else the jnp tile."""
+    if kernels:
+        from repro.kernels import ops
+        tile = ops.make_sw_tile_fn(params.match, params.mismatch, params.gap)
+    else:
+        tile = functools.partial(align_lib._sw_tile_fn, params)
+    return jax.jit(jax.vmap(tile))
 
 
 @functools.lru_cache(maxsize=None)
-def _dtw_tile_batched():
+def _dtw_tile_batched(kernels: bool):
+    if kernels:
+        from repro.kernels import ops
+        return jax.jit(jax.vmap(ops.dtw_tile_fn))
     return jax.jit(jax.vmap(dtw_lib._dtw_tile_fn))
 
 
@@ -167,7 +179,7 @@ def _sw_batched(a: np.ndarray, b: np.ndarray,
     bp = wavefront.pad_to_multiple(jnp.asarray(b, jnp.int32), tile, 1, 255)
     npad, mpad = ap.shape[1], bp.shape[1]
     mat, _, _, _ = wavefront.run_wavefront_batched(
-        _sw_tile_batched(params), ap, bp,
+        _sw_tile_batched(params, kmode.use_kernels()), ap, bp,
         top0=jnp.zeros((bsz, mpad), jnp.float32),
         left0=jnp.zeros((bsz, npad), jnp.float32),
         corner0=jnp.zeros((bsz,), jnp.float32),
@@ -185,7 +197,7 @@ def _dtw_batched(s: np.ndarray, r: np.ndarray, tile: int) -> jnp.ndarray:
     rp = wavefront.pad_to_multiple(jnp.asarray(r, jnp.float32), tile, 1, 1e18)
     npad, mpad = sp.shape[1], rp.shape[1]
     mat, _, _, _ = wavefront.run_wavefront_batched(
-        _dtw_tile_batched(), sp, rp,
+        _dtw_tile_batched(kmode.use_kernels()), sp, rp,
         top0=jnp.full((bsz, mpad), big, jnp.float32),
         left0=jnp.full((bsz, npad), big, jnp.float32),
         corner0=jnp.zeros((bsz,), jnp.float32),
@@ -294,13 +306,17 @@ class ChainAdapter(KernelAdapter):
 
     def launch(self, key, leaves):
         block = self.cfg.chain_block
-        if self.cfg.chain_mode == "blocked":
+        if self.cfg.chain_mode == "blocked" and self.svc.tuner is not None:
             # per-bucket autotuned block (fig9 sweep); only the blocked
             # schedule consumes a block size — fission/sequential ignore
             # it, so the lookup would be misleading there
             block = int(self.svc.tuner.get_bucketed("chain.block", key[0],
                                                     block))
-        fn = rm._chain_fn(self.cfg.chain_T, self.cfg.chain_mode, block)
+        if self.cfg.chain_mode == "fission" and kmode.use_kernels():
+            # the Pallas chain kernel is the fission schedule on the chip
+            fn = rm._chain_fn_pallas(self.cfg.chain_T)
+        else:
+            fn = rm._chain_fn(self.cfg.chain_T, self.cfg.chain_mode, block)
         return self.svc.dispatcher.run(fn, leaves)
 
     def collect(self, key, out, payloads):
@@ -393,8 +409,10 @@ class SortAdapter(KernelAdapter):
         return keys, vals
 
     def launch(self, key, leaves):
-        chunks = self.svc.tuner.get_bucketed("sort.chunks", key[0],
-                                             self.cfg.sort_chunks)
+        chunks = self.cfg.sort_chunks
+        if self.svc.tuner is not None:
+            chunks = self.svc.tuner.get_bucketed("sort.chunks", key[0],
+                                                 chunks)
         return self.svc.dispatcher.run(_sort_fn(int(chunks)), leaves)
 
     def collect(self, key, out, payloads):
@@ -503,11 +521,7 @@ class MapperAdapter(KernelAdapter):
 
         # -- chain (bucketed by padded anchor count) ---------------------
         windows: Dict[int, Tuple[float, int, int]] = {}
-        if cfg.use_pallas:
-            chain_fn = rm._chain_fn_pallas(cfg.band_T)
-        else:
-            mode = "blocked" if cfg.mode == "squire" else "sequential"
-            chain_fn = rm._chain_fn(cfg.band_T, mode, 16)
+        chain_fn = rm.chain_stage(cfg.mode, cfg.band_T)
         groups = bucketing.group_by_key(
             [(bucketing.round_up(max(len(anchors[i][0]), 1),
                                  cfg.anchor_bucket),)
@@ -564,14 +578,8 @@ class MapperAdapter(KernelAdapter):
 
     def _align_batched(self, a: np.ndarray, b: np.ndarray):
         cfg = self.cfg.mapper
-        if cfg.use_pallas or cfg.mode == "squire":
-            if cfg.use_pallas:
-                from repro.kernels import ops
-                p = cfg.sw_params
-                tile_b = jax.vmap(ops.make_sw_tile_fn(p.match, p.mismatch,
-                                                      p.gap))
-            else:
-                tile_b = _sw_tile_batched(cfg.sw_params)
+        if cfg.mode == "squire":
+            tile_b = _sw_tile_batched(cfg.sw_params, kmode.use_kernels())
             bsz = a.shape[0]
             ap = wavefront.pad_to_multiple(jnp.asarray(a), cfg.sw_tile,
                                            1, 255)
@@ -584,7 +592,7 @@ class MapperAdapter(KernelAdapter):
                 corner0=jnp.zeros((bsz,), jnp.float32),
                 tile_r=cfg.sw_tile, tile_c=cfg.sw_tile, assemble=True)
             return mat[:, :a.shape[1], :b.shape[1]]
-        fn, _ = rm._sw_fn("baseline", cfg.sw_tile, False, cfg.sw_params)
+        fn, _ = rm.sw_stage("baseline", cfg.sw_tile, cfg.sw_params)
         mats, _ = self.svc.dispatcher.run(fn, (a, b))
         return mats
 
@@ -663,7 +671,9 @@ class KernelService:
         self.reference = (None if reference is None
                           else np.asarray(reference, np.int8))
         self.lm = lm            # serve.Scheduler for the 'generate' kernel
-        self.tuner = tuner or Autotuner()
+        # per-bucket knobs come from an autotune cache only when one is
+        # passed; otherwise the ServiceConfig knobs alone decide
+        self.tuner = tuner
         self._index = None
         self._adapters: Dict[str, KernelAdapter] = {
             a.name: a(self) for a in _ADAPTERS}

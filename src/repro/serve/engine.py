@@ -508,6 +508,9 @@ def jit_paged_verify_step(cfg: ModelConfig):
 #     shard semantics on a single-device CI host).
 #   * mesh                     — jax.shard_map over the mesh axis: one
 #     fused program, one shard per device, block ids never cross shards.
+#     check_vma=False: the body is shard-local (no collectives, every
+#     output sharded), and the model's scans start from fresh constant
+#     carries that the varying-axes check would reject.
 #
 # Row vectors passed to these steps are SHARD-LOCAL physical rows (each
 # shard indexes only its own flat-pool segment); host-side block ops
@@ -600,9 +603,8 @@ def jit_sharded_decode_step(cfg: ModelConfig, num_shards: int,
                     tm(fuse, dense), tm(fuse, paged))
     else:
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.dispatch import _shard_map
-        run = _shard_map(
-            inner, mesh=mesh,
+        run = jax.shard_map(
+            inner, mesh=mesh, check_vma=False,
             in_specs=(P(), P(None, axis), P(None, axis), P(axis), P(axis),
                       P(axis), P(axis), P(axis), P(axis), P(axis)),
             out_specs=(P(axis), P(axis), P(None, axis), P(None, axis)))
@@ -680,9 +682,8 @@ def jit_sharded_chunk_step(cfg: ModelConfig, num_shards: int,
             return logits, tm(fuse, dense), tm(fuse, paged)
     else:
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.dispatch import _shard_map
-        smapped = _shard_map(
-            inner, mesh=mesh,
+        smapped = jax.shard_map(
+            inner, mesh=mesh, check_vma=False,
             in_specs=(P(), P(None, axis), P(None, axis), P(axis), P(axis),
                       P(axis), P(axis), P(axis)),
             out_specs=(P(axis), P(None, axis), P(None, axis)))
@@ -759,9 +760,8 @@ def jit_sharded_verify_step(cfg: ModelConfig, num_shards: int,
                     tm(fuse, dense), tm(fuse, paged))
     else:
         from jax.sharding import PartitionSpec as P
-        from repro.runtime.dispatch import _shard_map
-        run = _shard_map(
-            inner, mesh=mesh,
+        run = jax.shard_map(
+            inner, mesh=mesh, check_vma=False,
             in_specs=(P(), P(None, axis), P(None, axis), P(axis), P(axis),
                       P(axis), P(axis), P(axis), P(axis), P(axis),
                       P(axis), P(axis), P(axis), P(axis)),
@@ -838,7 +838,7 @@ def generate(params, cfg: ModelConfig, prompt, max_new_tokens: int,
              *, temperature: float = 0.0, top_k: int = 0,
              top_p: float = 1.0, eos_token: Optional[int] = None,
              prefill_chunk: int = 32, cache_slots: int = 0,
-             key: Optional[jnp.ndarray] = None):
+             key: Optional[jnp.ndarray] = None, batch: int = 1):
     """Per-request generation — the scheduler's single-request oracle.
 
     Consumes the prompt with the SAME chunked-prefill + decode-ramp
@@ -846,6 +846,14 @@ def generate(params, cfg: ModelConfig, prompt, max_new_tokens: int,
     over the first L-1 tokens, remainder teacher-forced through decode),
     so a Scheduler run is token-identical to mapping this over requests
     under greedy sampling. Returns (tokens: np-able (g,) int32, reason).
+
+    ``batch`` replicates the request over that many cache rows, so its
+    programs have the batch shape of a Scheduler with ``batch`` slots.
+    On the TPU, XLA compiles each batch size to its own rounding (a
+    one-row program folds the batch dim away; tiles follow the row
+    count), and bf16 logits of unrelated batch sizes differ in the last
+    bits — enough to flip a near-tied greedy pick. There the oracle for
+    an ``n``-slot Scheduler is ``generate(..., batch=n)``.
     """
     import numpy as np
 
@@ -853,29 +861,28 @@ def generate(params, cfg: ModelConfig, prompt, max_new_tokens: int,
     ln = int(prompt.shape[0])
     assert ln >= 1, "empty prompt"
     slots = cache_slots or (ln + max_new_tokens)
-    caches = T.init_caches(cfg, batch=1, slots=slots, per_slot_pos=True)
+    caches = T.init_caches(cfg, batch=batch, slots=slots, per_slot_pos=True)
     chunk_fn = jit_chunk_step(cfg)
     decode_fn = jit_slot_decode_step(cfg)
     if key is None:
         key = jax.random.PRNGKey(0)
 
+    rows = lambda x: jnp.full((batch,), x, x.dtype)
     ctx = 0
     while ln - 1 - ctx >= prefill_chunk:
-        toks = prompt[None, ctx:ctx + prefill_chunk]
-        _, caches = chunk_fn(params, caches, toks,
-                             jnp.asarray([ctx], jnp.int32))
+        toks = jnp.tile(prompt[None, ctx:ctx + prefill_chunk], (batch, 1))
+        _, caches = chunk_fn(params, caches, toks, rows(jnp.int32(ctx)))
         ctx += prefill_chunk
 
-    temps = jnp.asarray([temperature], jnp.float32)
-    tks = jnp.asarray([top_k], jnp.int32)
-    tps = jnp.asarray([top_p], jnp.float32)
+    temps = rows(jnp.float32(temperature))
+    tks = rows(jnp.int32(top_k))
+    tps = rows(jnp.float32(top_p))
     out, reason, last = [], "length", None
     while len(out) < max_new_tokens:
         tok = prompt[ctx] if ctx < ln else last
         key, ks = jax.random.split(key)
-        nxt, _, caches = decode_fn(params, caches, tok.reshape(1, 1),
-                                   jnp.asarray([ctx], jnp.int32), temps, ks,
-                                   tks, tps)
+        nxt, _, caches = decode_fn(params, caches, rows(tok)[:, None],
+                                   rows(jnp.int32(ctx)), temps, ks, tks, tps)
         ctx += 1
         last = nxt[0]
         if ctx >= ln:                       # prompt consumed: real sample

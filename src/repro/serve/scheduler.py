@@ -99,7 +99,7 @@ from repro.obs import sampler as obs_sampler
 from repro.obs import trace as obs_trace
 from repro.runtime import bucketing
 from repro.serve import engine
-from repro.serve.slots import SlotManager, _attn_view_len
+from repro.serve.slots import MIN_CHUNK_ROWS, SlotManager, _attn_view_len
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1018,7 +1018,7 @@ class Scheduler:
                 # unpadded and take rows back in input order
                 idx = list(need)
             else:
-                bsz = bucketing.round_up_pow2(m, 1)
+                bsz = bucketing.round_up_pow2(m, MIN_CHUNK_ROWS)
                 idx = need + [need[0]] * (bsz - m)  # pad-by-repeat
             toks = np.stack([
                 self._by_slot[s].prompt[self._by_slot[s].ctx:

@@ -55,6 +55,11 @@ from repro.serve.paging import (BlockPool, PageTable, PrefixIndex,
                                 SwapEntry, SwapStore)
 
 _SLOT_AXIS = 1      # every per_slot_pos cache leaf: (periods, B, ...)
+# Chunk sub-batches never run a lone row: on the TPU a one-row program
+# folds its batch dim away and rounds differently from the multi-row chunk
+# programs, so a slot's stream would depend on how many other slots
+# happened to prefill beside it.
+MIN_CHUNK_ROWS = 2
 
 
 @jax.jit
@@ -1158,7 +1163,8 @@ class _ShardedPagedBacking:
             s = slot // k
             t_of[j] = len(per[s])
             per[s].append(j)
-        m = bucketing.round_up_pow2(max(len(p) for p in per), 1)
+        m = bucketing.round_up_pow2(max(len(p) for p in per),
+                                    MIN_CHUNK_ROWS)
         idx_a = np.zeros((n, m), np.int32)
         tok_a = np.zeros((n, m) + tokens.shape[1:], tokens.dtype)
         pos_a = np.zeros((n, m), pos_in.dtype)

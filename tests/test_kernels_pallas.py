@@ -181,6 +181,22 @@ def test_radix_sort_chunks_full_pipeline():
         np.testing.assert_array_equal(keys[c][sv[c]], sk[c])
 
 
+@pytest.mark.parametrize("n,chunks", [(3, 8), (300, 1), (1000, 4)])
+def test_radix_sort_kernel_path(n, chunks, kernel_stages):
+    """core.sort's TPU branch (chunks padded to the 128-lane key block and
+    sorted by the rank kernel) is the stable sort, ties and pads included."""
+    from repro.core import sort as R
+    rng = np.random.default_rng(n)
+    keys = rng.integers(0, 64, n, dtype=np.uint32)
+    keys[::7] = 0xFFFFFFFF                  # real keys equal to the pad key
+    vals = rng.integers(0, 2**31, n).astype(np.int32)
+    sk, sv = R.radix_sort(jnp.asarray(keys), jnp.asarray(vals),
+                          num_chunks=chunks, min_parallel=0)
+    order = np.argsort(keys, kind="stable")
+    np.testing.assert_array_equal(np.asarray(sk), keys[order])
+    np.testing.assert_array_equal(np.asarray(sv), vals[order])
+
+
 # --------------------------------------------------------------------------
 # flash attention
 # --------------------------------------------------------------------------
@@ -244,3 +260,20 @@ def test_sw_tile_scoring_params():
     mat, best = A.sw_tiled(jnp.asarray(a), jnp.asarray(b), p,
                            tile_r=8, tile_c=8, tile_fn=fn)
     np.testing.assert_allclose(mat, want, rtol=1e-5, atol=1e-4)
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False),
+                                               ("gpu", None)])
+def test_kernel_mode_from_platform(monkeypatch, backend, interpret):
+    """Compiled on a TPU, interpreted on the CPU, and no mode at all
+    elsewhere: an unknown backend raises instead of falling back."""
+    from repro.kernels import mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="no Pallas kernel mode"):
+            mode.interpret()
+        with pytest.raises(RuntimeError):
+            mode.use_kernels()
+    else:
+        assert mode.interpret() is interpret
+        assert mode.use_kernels() is not interpret

@@ -84,6 +84,35 @@ def test_serve_cli():
     assert "[serve] decode:" in res.stdout
 
 
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(tmp_path, placed):
+    """With JAX_COMPILATION_CACHE_DIR set the entry points set nothing and
+    compiles land there; without it the cache is the checkout's fixed
+    .jax_cache/ (CPU subprocess: the config is process state)."""
+    env = {**ENV, "JAX_PLATFORMS": "cpu"}
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = str(Path(SRC).parent / ".jax_cache")
+    if placed:
+        want = str(tmp_path / "cc")
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    code = textwrap.dedent("""
+        import jax, jax.numpy as jnp
+        from repro.launch import compile_cache
+        print(compile_cache.configure())
+        print(jax.config.jax_compilation_cache_dir)
+        if {placed}:     # write an entry (thresholds off for this probe)
+            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+            jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()
+    """).format(placed=placed)
+    res = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert res.stdout.split() == [want, want]
+    if placed:
+        assert any(Path(want).iterdir())
+
+
 _DRYRUN_ARTIFACTS = sorted(
     (Path(__file__).resolve().parents[1] / "experiments" / "dryrun")
     .glob("*__single.json"))

@@ -69,3 +69,28 @@ def test_short_read_rejected(ref):
     mapper = ReadMapper(ref, MapperConfig())
     res = mapper.map_read(np.zeros(10, np.int8))
     assert res.pos == -1
+
+
+def test_kernel_stages_match_jnp_stages(ref, reads, kernel_stages):
+    """The TPU branch of the squire mapper (Pallas chain, SW tile and
+    rank-kernel sort; here under the interpreter), per read and through
+    the batched service, maps every read exactly as the jnp stages do."""
+    from repro.kernels import mode
+    from repro.runtime import KernelService, Request, ServiceConfig
+
+    rs = [r for r, _ in reads]
+    cfg = MapperConfig(mode="squire")
+    got = ReadMapper(ref, cfg).map_reads(rs)
+    batched = KernelService(ServiceConfig(mapper=cfg), reference=ref).submit(
+        [Request("map", {"read": r}) for r in rs])
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mode, "use_kernels", lambda: False)
+        import jax
+        jax.clear_caches()
+        want = ReadMapper(ref, cfg).map_reads(rs)
+        jax.clear_caches()
+    for a, b, c in zip(got, batched, want):
+        assert a.pos == b.pos == c.pos >= 0
+        assert a.sw_score == b.sw_score == c.sw_score
+        assert a.chain_score == b.chain_score == c.chain_score
+        assert a.n_anchors == b.n_anchors == c.n_anchors

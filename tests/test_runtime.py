@@ -331,6 +331,51 @@ def test_service_mapper_matches_readmapper():
     assert len(seeds[0]["q"]) == direct[0].n_anchors
 
 
+@pytest.mark.parametrize("kernel", ["chain", "sw", "dtw", "sort"])
+def test_service_kernel_stages_match_jnp(kernel, kernel_stages, rng):
+    """The TPU branch (Pallas chain / SW / DTW tiles / rank-kernel sort,
+    here under the interpreter) returns what the jnp stages return."""
+    svc = KernelService(CFG)
+    reqs, want = [], []
+    for n in (5, 40, 70):
+        if kernel == "chain":
+            q = np.sort(rng.integers(0, 400, n)).astype(np.int32)
+            r = np.sort(rng.integers(0, 5000, n)).astype(np.int32)
+            reqs.append(Request("chain", {"q": q, "r": r}))
+            want.append(jax.jit(partial(
+                chain_lib.chain_anchors, T=CFG.chain_T, mode="sequential"))(
+                    jnp.asarray(q), jnp.asarray(r)))
+        elif kernel == "sw":
+            a = rng.integers(0, 4, n).astype(np.int32)
+            b = rng.integers(0, 4, n + 9).astype(np.int32)
+            reqs.append(Request("sw", {"a": a, "b": b}))
+            want.append(float(np.asarray(align_lib.sw_ref(
+                jnp.asarray(a), jnp.asarray(b), CFG.sw_params)).max()))
+        elif kernel == "dtw":
+            s = rng.normal(size=n).astype(np.float32)
+            r = rng.normal(size=n + 3).astype(np.float32)
+            reqs.append(Request("dtw", {"s": s, "r": r}))
+            want.append(float(dtw_lib.dtw_tiled(
+                jnp.asarray(s), jnp.asarray(r), tile_r=CFG.dtw_tile,
+                tile_c=CFG.dtw_tile)[1]))
+        else:
+            keys = rng.integers(0, 2**8, n, dtype=np.uint32)   # many ties
+            reqs.append(Request("sort", {"keys": keys}))
+            order = np.argsort(keys, kind="stable")
+            want.append((keys[order], order.astype(np.int32)))
+    for got, w in zip(svc.submit(reqs), want):
+        if kernel == "chain":
+            np.testing.assert_array_equal(got["f"], np.asarray(w[0]))
+            np.testing.assert_array_equal(got["pred"], np.asarray(w[1]))
+        elif kernel == "sw":
+            assert float(got["score"]) == w
+        elif kernel == "dtw":
+            assert float(got["distance"]) == w
+        else:
+            np.testing.assert_array_equal(got["keys"], w[0])
+            np.testing.assert_array_equal(got["vals"], w[1])
+
+
 # --------------------------------------------------------------------------
 # autotune
 # --------------------------------------------------------------------------
@@ -472,9 +517,29 @@ def test_service_uses_bucketed_chain_block(tmp_path):
     q = np.sort(rng.integers(0, 400, 40)).astype(np.int32)
     r = np.sort(rng.integers(0, 5000, 40)).astype(np.int32)
     req = [Request("chain", {"q": q, "r": r})]
-    # untuned cache (empty file path) vs per-bucket tuned block
+    # per-bucket tuned block vs the ServiceConfig knob (no tuner)
     tuned = KernelService(cfg, tuner=t).submit(req)[0]
-    default = KernelService(
-        cfg, tuner=Autotuner(str(tmp_path / "empty.json"))).submit(req)[0]
+    default = KernelService(cfg).submit(req)[0]
     np.testing.assert_array_equal(tuned["f"], default["f"])
     np.testing.assert_array_equal(tuned["pred"], default["pred"])
+
+
+def test_service_reads_no_autotune_file_by_default(monkeypatch, rng):
+    """Without an explicit tuner the service compiles from its
+    ServiceConfig knobs alone: no Autotuner (so no file outside the
+    checkout) is ever built."""
+    import repro.runtime.autotune as autotune_mod
+
+    def refuse(self, path=None):
+        raise AssertionError("the default service opened an autotune cache")
+
+    monkeypatch.setattr(autotune_mod.Autotuner, "__init__", refuse)
+    import dataclasses
+    svc = KernelService(dataclasses.replace(CFG, chain_mode="blocked"))
+    assert svc.tuner is None
+    keys = rng.integers(0, 2**32, 40, dtype=np.uint32)
+    q = np.sort(rng.integers(0, 400, 40)).astype(np.int32)
+    r = np.sort(rng.integers(0, 5000, 40)).astype(np.int32)
+    got = svc.submit([Request("sort", {"keys": keys}),
+                      Request("chain", {"q": q, "r": r})])
+    np.testing.assert_array_equal(got[0]["keys"], np.sort(keys))

@@ -394,9 +394,12 @@ def _check_shard_invariants(s):
 def _random_serving_trace(gemma, seed, n_shards, preempt, steal=True):
     cfg, params = gemma
     rng = np.random.default_rng(seed)
+    # >= 5 blocks of 8 per shard: the longest drawn request (27 prompt +
+    # 9 new = 36 positions) must fit an empty shard, or submit rightly
+    # refuses it (fits_pool) before any invariant is exercised
     sc = dataclasses.replace(
         _BASE, num_slots=4, mesh_shards=n_shards,
-        num_blocks=int(rng.integers(4, 9)), preempt=preempt,
+        num_blocks=int(rng.integers(5, 9)), preempt=preempt,
         placement=str(rng.choice(["least_blocks", "round_robin"])),
         steal=steal, prefix_sharing=bool(rng.integers(0, 2)))
     s = Scheduler(cfg, params, sc)

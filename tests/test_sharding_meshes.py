@@ -88,7 +88,8 @@ _SUB = textwrap.dedent("""
         ck.save(2, state, extra={"next_step": 2})
 
         # elastic: restore onto a *different* mesh shape (4, 2)
-        mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        mesh2 = auto_mesh((4, 2), ("data", "model"))
         configure(mesh2)
         st_sh2 = state_shardings(shapes, mesh2)
         state2, extra = ck.restore(shapes, shardings=st_sh2)
