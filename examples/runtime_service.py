@@ -10,12 +10,14 @@ identical; the wall-clock ratio is the dispatch-layer win.
 
     PYTHONPATH=src python examples/runtime_service.py [--requests 64]
 
-Observability (PR 6): ``--trace out.json`` records every bucket
-dispatch as Chrome trace events on the dispatcher track (load in
+Observability: ``--trace out.json`` records every bucket dispatch as
+Chrome trace events on the dispatcher track, the pipeline's fences
+(``fence`` spans on the runtime track: how long a batch in flight kept
+the host waiting) and the mapper's stages on the map track (load in
 https://ui.perfetto.dev); ``--metrics`` dumps the metrics registry —
 ``runtime.dispatch.*`` compile-cache hit/miss counts and
-compile-vs-execute wall time, per-bucket splits, pipeline fence times,
-and per-kernel request counts — as JSON on exit.
+compile-vs-execute wall time, per-bucket splits, and per-kernel request
+counts — as JSON on exit.
 """
 
 import argparse

@@ -132,9 +132,10 @@ def rwkv_time_mix(params, cfg: RWKVConfig, x: Array,
         shard_fold = lambda z: shard_act(z, "ssm_fold", None, None)
         rf, wf, kf, vf = map(shard_fold, (rf, wf, kf, vf))
         s0f = s0.reshape(b * h, hd, hd) if s0 is not None else None
-        yf, s_fin = la.wkv_chunked(rf, wf, kf, vf, None, s0f,
-                                   chunk=chunk or cfg.scan_chunk,
-                                   out_dtype=dt)
+        with jax.named_scope("wkv"):
+            yf, s_fin = la.wkv_chunked(rf, wf, kf, vf, None, s0f,
+                                       chunk=chunk or cfg.scan_chunk,
+                                       out_dtype=dt)
         yf = shard_fold(yf)
         uf = jnp.broadcast_to(params["u"][None], (b, h, hd))             .reshape(b * h, hd)
         bonus = jnp.einsum("btk,bk,btk->bt", rf.astype(jnp.float32),
@@ -151,9 +152,10 @@ def rwkv_time_mix(params, cfg: RWKVConfig, x: Array,
 
         rf, wf, kf, vf = map(fold, (r, w, k, v))
         s0f = s0.reshape(b * h, hd, hd) if s0 is not None else None
-        yf, s_fin = la.wkv_chunked(rf, wf, kf, vf, None, s0f,
-                                   chunk=chunk or cfg.scan_chunk,
-                                   out_dtype=dt)
+        with jax.named_scope("wkv"):
+            yf, s_fin = la.wkv_chunked(rf, wf, kf, vf, None, s0f,
+                                       chunk=chunk or cfg.scan_chunk,
+                                       out_dtype=dt)
         uf = jnp.broadcast_to(params["u"][None], (b, h, hd)) \
             .reshape(b * h, hd)
         bonus = jnp.einsum("btk,bk,btk->bt", rf.astype(jnp.float32),
@@ -192,8 +194,9 @@ def rwkv_time_mix_decode(params, cfg: RWKVConfig, x: Array, state: dict):
 
     fold = lambda z: z.reshape(b * h, hd)
     s0 = state["s"].reshape(b * h, hd, hd)
-    yf, s_fin = la.wkv_decode_step(fold(r), fold(w), fold(k), fold(v),
-                                   None, s0)
+    with jax.named_scope("wkv"):
+        yf, s_fin = la.wkv_decode_step(fold(r), fold(w), fold(k), fold(v),
+                                       None, s0)
     uf = jnp.broadcast_to(params["u"][None], (b, h, hd)).reshape(b * h, hd)
     bonus = jnp.einsum("bk,bk,bk->b", fold(r).astype(jnp.float32), uf,
                        fold(k).astype(jnp.float32))

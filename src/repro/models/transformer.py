@@ -274,7 +274,8 @@ def apply_model(params, cfg: ModelConfig, *, tokens: Optional[Array] = None,
         table = params["embed"]["table"]
     else:
         table = params["unembed"]["table"]
-    logits = L.logits({"table": table}, x)
+    with jax.named_scope("lm_head"):
+        logits = L.logits({"table": table}, x)
     logits = shard_act(logits, "batch", "seq", "vocab")
     return logits, aux_loss, new_caches
 

@@ -8,13 +8,20 @@ that pin the surface.
               so the legacy per-component ``stats()`` dicts stay the
               source of truth and one ``REGISTRY.snapshot()`` sees the
               whole stack.
-  * trace   — bounded ring buffer of typed span/instant/counter events
-              (admit / prefill-chunk / decode-tick / preempt / swap /
-              retire / bucket-dispatch / jit-compile / slo-fire /
-              backpressure-on / metric counter tracks), a no-op when
-              disabled, exported to JSONL or Chrome trace-event JSON
-              (drop into https://ui.perfetto.dev: one track per slot
-              plus scheduler/dispatcher/slo/control/metrics tracks).
+  * trace   — spans on the JAX profiler's clock: while the profiler
+              records, each span (the scheduler's admit / prefill-chunk /
+              decode-tick, the mapper's seed / chain / align stages, the
+              pipeline's fences, each instrumented jit call) is a
+              ``repro.<track>.<name>`` annotation in its trace, beside
+              the device's operations. With the ring enabled, typed
+              span/instant/counter events (also preempt / swap / retire
+              / bucket-dispatch / jit-compile / slo-fire /
+              backpressure-on / metric counter tracks) go into a
+              bounded ring buffer for Perfetto-by-hand use, exported to
+              JSONL or Chrome trace-event JSON (drop into
+              https://ui.perfetto.dev: one track per slot plus
+              scheduler/dispatcher/slo/control/metrics tracks). A no-op
+              when neither records.
   * sampler — tick-driven snapshot ring over the registry: timestamped
               samples, counter rates (tokens/sec, swap bytes/sec), a
               JSONL time-series export and Perfetto counter tracks —

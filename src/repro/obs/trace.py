@@ -1,36 +1,58 @@
-"""Structured tracer: a bounded ring of typed span/instant events.
+"""Structured tracer: spans on the JAX profiler's clock, and a bounded
+ring of typed span/instant events.
 
 The paper reasons about where *cycles* go (sync overhead vs compute,
 Fig. 7); the serving runtime needs the same story for where *ticks* go —
 which slot was prefilling, decoding, swapped out or idle at every
-moment. Components record events through a context-manager/stamp API
-that compiles to a no-op when the tracer is disabled (the hot decode
-loop pays one attribute check per event site), into a bounded ring
-buffer (oldest events drop, ``dropped`` counts them — tracing never
-OOMs a long serve).
+moment, and which host stage kept the device waiting.
+
+Two sinks, one API:
+
+  * the profiler's trace — while ``jax.profiler`` is recording (e.g.
+    ``jax.profiler.start_trace``), ``Tracer.span(name, track, **args)``
+    opens a ``jax.profiler.TraceAnnotation`` named
+    ``repro.<track>.<name>`` with ``args`` as its stats, so program
+    stages land on the same clock as the device's operations and a
+    device idle gap can be attributed to the host stage around it.
+    ``instrumented_jit`` wraps each call in ``repro.jit.<name>``.
+  * the ring — with ``Tracer(enabled=True)``, spans, instants and
+    counter samples go into a bounded ring buffer (oldest events drop,
+    ``dropped`` counts them — tracing never OOMs a long serve), for
+    Perfetto-by-hand use through the exporters below.
+
+Spans whose ends the caller stamps (``complete()``: per-slot phases that
+straddle many ticks, ``jit-compile``) are ring-only: a profiler
+annotation must open and close around the code it covers. Instants and
+counter samples are ring-only too. With the profiler off and the ring
+disabled, an event site costs one check (a span site returns the shared
+no-op context manager).
 
 Event kinds (``name`` on a ``track``):
 
-  scheduler track  — ``decode-tick``, ``prefill-chunk`` spans; ``admit``
-                     instants
+  scheduler track  — ``decode-tick``, ``prefill-chunk``, ``admit`` spans;
+                     ``submit`` / ``steal`` instants
   slot<N> tracks   — per-request phase spans ``prefill`` / ``decode``
                      (args carry the rid) bracketed by ``admit`` /
                      ``retire`` / ``preempt`` / ``swap-out`` /
                      ``swap-in`` instants
+  map track        — the mapper's stages: ``seed``, ``chain``, ``align``
+                     and, inside it, ``align.walk`` (one per shape group;
+                     args ``tiles``, ``batch``) and ``align.fetch``
+  runtime track    — ``fence``: the pipeline waiting on a batch in flight
   dispatcher track — ``bucket-dispatch`` spans, ``jit-compile`` spans
                      (recorded by ``instrumented_jit`` wrappers)
 
-Exporters:
+Exporters (of the ring):
 
   * ``export_jsonl``  — one event dict per line (grep/pandas-friendly).
   * ``export_chrome`` — Chrome trace-event JSON: open chrome://tracing
     or https://ui.perfetto.dev and drop the file in. One thread (track)
     per slot plus scheduler/dispatcher threads, named and sorted.
 
-``get_tracer()`` returns the process-wide tracer (disabled by default);
-benchmarks/examples enable tracing by installing their own with
-``set_tracer(Tracer(enabled=True))`` or by passing a Tracer explicitly
-to the component (``Scheduler(..., tracer=t)``).
+``get_tracer()`` returns the process-wide tracer (ring disabled by
+default); benchmarks/examples enable the ring by installing their own
+with ``set_tracer(Tracer(enabled=True))`` or by passing a Tracer
+explicitly to the component (``Scheduler(..., tracer=t)``).
 """
 
 from __future__ import annotations
@@ -41,7 +63,12 @@ import json
 import time
 from typing import Any, Dict, List, Optional
 
+import jax
+
 from repro.obs import metrics as _metrics
+
+#: True while the JAX profiler records host annotations
+_profiling = jax.profiler.TraceAnnotation.is_enabled
 
 
 @dataclasses.dataclass
@@ -73,30 +100,43 @@ class _Noop:
 _NOOP = _Noop()
 
 
+def _annotation(name: str, track: str, args):
+    return jax.profiler.TraceAnnotation(f"repro.{track}.{name}",
+                                        **(args or {}))
+
+
 class _Span:
-    """Open span: records a complete event at __exit__."""
+    """Open span: records a complete event at __exit__, and covers the
+    same code with a profiler annotation when one was given."""
 
-    __slots__ = ("tracer", "name", "track", "args", "t0")
+    __slots__ = ("tracer", "name", "track", "args", "t0", "ann")
 
-    def __init__(self, tracer: "Tracer", name: str, track: str, args):
+    def __init__(self, tracer: "Tracer", name: str, track: str, args,
+                 ann=None):
         self.tracer = tracer
         self.name = name
         self.track = track
         self.args = args
         self.t0 = 0.0
+        self.ann = ann
 
     def __enter__(self):
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.tracer.complete(self.name, self.track, self.t0,
                              time.perf_counter(), **(self.args or {}))
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 
 class Tracer:
-    """Bounded ring buffer of Events; disabled == hard no-op."""
+    """Spans on the profiler's clock while it records, and a bounded
+    ring buffer of Events when ``enabled``; neither == hard no-op."""
 
     def __init__(self, enabled: bool = False, capacity: int = 65536):
         self.enabled = enabled
@@ -119,11 +159,16 @@ class Tracer:
 
     def span(self, name: str, track: str, **args):
         """``with tracer.span("decode-tick", "scheduler", live=3):`` —
-        records a complete event at exit; the shared no-op when
-        disabled."""
-        if not self.enabled:
+        a ``repro.scheduler.decode-tick`` profiler annotation while the
+        profiler records, and a complete ring event at exit when
+        ``enabled``; the shared no-op when neither."""
+        if not (self.enabled or _profiling()):
             return _NOOP
-        return _Span(self, name, track, args or None)
+        if not self.enabled:
+            return _annotation(name, track, args)
+        return _Span(self, name, track, args or None,
+                     _annotation(name, track, args) if _profiling()
+                     else None)
 
     def instant(self, name: str, track: str, **args):
         if not self.enabled:
@@ -145,7 +190,8 @@ class Tracer:
     def complete(self, name: str, track: str, t0: float, t1: float,
                  **args):
         """Record a span whose endpoints the caller stamped (phases that
-        straddle many scheduler ticks can't use the context manager)."""
+        straddle many scheduler ticks can't use the context manager).
+        Ring-only: the profiler's trace never sees it."""
         if not self.enabled:
             return
         self._push(Event(name, track, "X", t0, max(t1 - t0, 0.0),
@@ -211,8 +257,8 @@ class Tracer:
                     default=str) + "\n")
 
 
-#: process-wide tracer, disabled by default (every event site is then a
-#: single attribute check)
+#: process-wide tracer, ring disabled by default (every event site is
+#: then a single check while the profiler is off)
 _TRACER = Tracer(enabled=False)
 
 
@@ -232,7 +278,10 @@ def set_tracer(tracer: Tracer) -> Tracer:
 # ---------------------------------------------------------------------------
 
 def instrumented_jit(jfn, name: str, prefix: str):
-    """Wrap a ``jax.jit``-ed callable: each call is timed, and a call
+    """Wrap a ``jax.jit``-ed callable: while the profiler records, each
+    call runs inside a ``repro.jit.<name>`` annotation (so programs that
+    share a traced name, such as the serving steps' ``jit_run``, are
+    told apart on the host plane); each call is timed, and a call
     that grew the function's compile cache (``_cache_size()`` — a new
     (shape, dtype) signature traced+compiled) is counted as a *compile*
     and recorded as a ``jit-compile`` span on the dispatcher track;
@@ -253,7 +302,9 @@ def instrumented_jit(jfn, name: str, prefix: str):
     def wrapper(*args, **kwargs):
         n0 = cache_size() if cache_size is not None else -1
         t0 = time.perf_counter()
-        out = jfn(*args, **kwargs)
+        with (jax.profiler.TraceAnnotation(f"repro.jit.{name}")
+              if _profiling() else _NOOP):
+            out = jfn(*args, **kwargs)
         t1 = time.perf_counter()
         if cache_size is not None and cache_size() > n0:
             misses.inc()

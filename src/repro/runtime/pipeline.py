@@ -18,13 +18,12 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from collections import deque
 from typing import Callable, Iterable, Iterator, TypeVar
 
 import jax
 
-from repro.obs import metrics as obs_metrics
+from repro.obs import trace as obs_trace
 
 T = TypeVar("T")
 R = TypeVar("R")
@@ -85,16 +84,13 @@ def run_pipelined(items: Iterable[T], launch: Callable[[T], R],
     async dispatch does this for jitted calls); the fence happens here,
     just before the result is handed to the caller — by which time the
     next batches are already padded (prefetch thread) and launched.
+    Each fence is a ``repro.runtime.fence`` span: how long results in
+    flight keep the host waiting (near-zero fences mean the overlap is
+    doing its job).
     """
-    # fence wall-time histogram: how long results-in-flight keep the host
-    # waiting — near-zero fences mean the overlap is doing its job
-    h_fence = obs_metrics.REGISTRY.histogram("runtime.pipeline.fence_ms")
-
     def fence(x):
-        t0 = time.perf_counter()
-        out = jax.block_until_ready(x)
-        h_fence.observe((time.perf_counter() - t0) * 1e3)
-        return out
+        with obs_trace.get_tracer().span("fence", "runtime"):
+            return jax.block_until_ready(x)
 
     inflight: deque = deque()
     for item in prefetched(items, buffer=buffer):

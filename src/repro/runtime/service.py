@@ -47,6 +47,7 @@ from repro.core.semiring import SEMIRINGS, finite_zero
 from repro.kernels import mode as kmode
 from repro.obs import metrics as obs_metrics
 from repro.obs import sampler as obs_sampler
+from repro.obs import trace as obs_trace
 from repro.runtime import bucketing
 from repro.runtime.autotune import Autotuner
 from repro.runtime.dispatch import Dispatcher
@@ -491,13 +492,18 @@ class MapperAdapter(KernelAdapter):
     """payload {read} -> MapResult: the end-to-end mapper with each stage
     batched across the in-flight requests (the paper's Fig. 8 pipeline at
     traffic scale). Stage functions and padding are shared with
-    ReadMapper, so results are bit-identical to per-read mapping."""
+    ReadMapper, so results are bit-identical to per-read mapping.
+
+    Each stage is a span on the map track (``seed``, ``chain``,
+    ``align``; inside align one ``align.walk`` per shape group and the
+    ``align.fetch`` of its matrices), opened on the calling thread."""
 
     name = "map"
 
     def run(self, payloads: List[Dict]) -> List[Any]:
         cfg = self.cfg.mapper
         svc = self.svc
+        tracer = obs_trace.get_tracer()
         reads = [np.asarray(p["read"]) for p in payloads]
         results: List[Optional[rm.MapResult]] = [None] * len(reads)
 
@@ -510,8 +516,9 @@ class MapperAdapter(KernelAdapter):
 
         # -- seed: the same adapter the standalone "seed" kernel uses ----
         anchors: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
-        seeded = svc._adapters["seed"].run(
-            [{"read": reads[i]} for i in live])
+        with tracer.span("seed", "map"):
+            seeded = svc._adapters["seed"].run(
+                [{"read": reads[i]} for i in live])
         for i, got in zip(live, seeded):
             nv = len(got["q"])
             if nv < 2:
@@ -521,60 +528,82 @@ class MapperAdapter(KernelAdapter):
 
         # -- chain (bucketed by padded anchor count) ---------------------
         windows: Dict[int, Tuple[float, int, int]] = {}
-        chain_fn = rm.chain_stage(cfg.mode, cfg.band_T)
-        groups = bucketing.group_by_key(
-            [(bucketing.round_up(max(len(anchors[i][0]), 1),
-                                 cfg.anchor_bucket),)
-             for i in sorted(anchors)])
-        order = sorted(anchors)
-        for (nb,), rows in groups.items():
-            idxs = [order[r] for r in rows]
-            parts = [rm.chain_payload(anchors[i][0], anchors[i][1], cfg)
-                     for i in idxs]
-            qp = np.stack([x[0] for x in parts])
-            rp = np.stack([x[1] for x in parts])
-            vp = np.stack([x[2] for x in parts])
-            f, pred = jax.tree_util.tree_map(
-                np.asarray, svc.dispatcher.run(chain_fn, (qp, rp, vp)))
-            for row, i in enumerate(idxs):
-                qv, rv = anchors[i]
-                nv = len(qv)
-                chains = chain_lib.backtrack(f[row][:nv], pred[row][:nv],
-                                             min_score=cfg.min_chain_score)
-                if not chains:
-                    results[i] = rm.MapResult(-1, 0.0, 0.0, nv, 0)
-                    continue
-                score, members = chains[0]
-                lo, hi = rm.chain_window(qv, rv, members, len(reads[i]),
-                                         len(svc.reference), cfg)
-                if hi - lo < cfg.k:
-                    results[i] = rm.MapResult(-1, 0.0, score, nv, 0)
-                else:
-                    windows[i] = (score, lo, hi)
+        with tracer.span("chain", "map"):
+            chain_fn = rm.chain_stage(cfg.mode, cfg.band_T)
+            groups = bucketing.group_by_key(
+                [(bucketing.round_up(max(len(anchors[i][0]), 1),
+                                     cfg.anchor_bucket),)
+                 for i in sorted(anchors)])
+            order = sorted(anchors)
+            for (nb,), rows in groups.items():
+                idxs = [order[r] for r in rows]
+                parts = [rm.chain_payload(anchors[i][0], anchors[i][1],
+                                          cfg) for i in idxs]
+                qp = np.stack([x[0] for x in parts])
+                rp = np.stack([x[1] for x in parts])
+                vp = np.stack([x[2] for x in parts])
+                f, pred = jax.tree_util.tree_map(
+                    np.asarray, svc.dispatcher.run(chain_fn, (qp, rp, vp)))
+                for row, i in enumerate(idxs):
+                    qv, rv = anchors[i]
+                    nv = len(qv)
+                    chains = chain_lib.backtrack(
+                        f[row][:nv], pred[row][:nv],
+                        min_score=cfg.min_chain_score)
+                    if not chains:
+                        results[i] = rm.MapResult(-1, 0.0, 0.0, nv, 0)
+                        continue
+                    score, members = chains[0]
+                    lo, hi = rm.chain_window(qv, rv, members,
+                                             len(reads[i]),
+                                             len(svc.reference), cfg)
+                    if hi - lo < cfg.k:
+                        results[i] = rm.MapResult(-1, 0.0, score, nv, 0)
+                    else:
+                        windows[i] = (score, lo, hi)
 
         # -- align (bucketed by padded (read, window) shape) -------------
-        pend = sorted(windows)
-        pairs = {}
-        for i in pend:
-            _, lo, hi = windows[i]
-            window = svc.reference[lo:hi].astype(np.int32)
-            pairs[i] = rm.align_payload(reads[i], window, cfg)
-        groups = bucketing.group_by_key(
-            [(pairs[i][0].shape[0], pairs[i][1].shape[0]) for i in pend])
-        for (na, nb), rows in groups.items():
-            idxs = [pend[r] for r in rows]
-            a = np.stack([pairs[i][0] for i in idxs])
-            b = np.stack([pairs[i][1] for i in idxs])
-            mats = np.asarray(self._align_batched(a, b))
-            for row, i in enumerate(idxs):
-                chain_score, lo, hi = windows[i]
-                mat = mats[row]
-                sw_score = float(mat.max())
-                results[i] = rm.MapResult(
-                    pos=lo, sw_score=sw_score, chain_score=chain_score,
-                    n_anchors=len(anchors[i][0]),
-                    align_cells=len(reads[i]) * (hi - lo))
+        with tracer.span("align", "map"):
+            pend = sorted(windows)
+            pairs = {}
+            for i in pend:
+                _, lo, hi = windows[i]
+                window = svc.reference[lo:hi].astype(np.int32)
+                pairs[i] = rm.align_payload(reads[i], window, cfg)
+            groups = bucketing.group_by_key(
+                [(pairs[i][0].shape[0], pairs[i][1].shape[0])
+                 for i in pend])
+            for (na, nb), rows in groups.items():
+                idxs = [pend[r] for r in rows]
+                a = np.stack([pairs[i][0] for i in idxs])
+                b = np.stack([pairs[i][1] for i in idxs])
+                tiles = self._walk_tiles(a.shape[1], b.shape[1])
+                with tracer.span("align.walk", "map", tiles=tiles,
+                                 batch=len(idxs)):
+                    mats = self._align_batched(a, b)
+                with tracer.span("align.fetch", "map"):
+                    mats = np.asarray(mats)
+                svc.align_walks += 1
+                svc.align_tiles += tiles
+                for row, i in enumerate(idxs):
+                    chain_score, lo, hi = windows[i]
+                    mat = mats[row]
+                    sw_score = float(mat.max())
+                    results[i] = rm.MapResult(
+                        pos=lo, sw_score=sw_score, chain_score=chain_score,
+                        n_anchors=len(anchors[i][0]),
+                        align_cells=len(reads[i]) * (hi - lo))
         return results
+
+    def _walk_tiles(self, na: int, nb: int) -> int:
+        """Tile calls of one align walk over (B, na) x (B, nb) operands:
+        the eager wavefront of squire mode pads both to the tile and
+        launches once per tile; other modes run one program, no walk."""
+        cfg = self.cfg.mapper
+        if cfg.mode != "squire":
+            return 0
+        t = cfg.sw_tile
+        return ((na + t - 1) // t) * ((nb + t - 1) // t)
 
     def _align_batched(self, a: np.ndarray, b: np.ndarray):
         cfg = self.cfg.mapper
@@ -683,6 +712,9 @@ class KernelService:
             dict.fromkeys(self.kernels, 0))
         self.submit_count = 0
         self.deduped_requests = 0
+        # the mapper's align walks, and the tile calls they launched
+        self.align_walks = 0
+        self.align_tiles = 0
         obs_metrics.REGISTRY.register_provider("runtime.service", self)
 
     @property
@@ -702,9 +734,12 @@ class KernelService:
 
     def metrics(self) -> Dict[str, Any]:
         """Registry 'runtime.service' provider: per-kernel request
-        traffic (``requests.<kernel>``) + bulk submit count."""
+        traffic (``requests.<kernel>``), bulk submit count, and the
+        mapper's align walks and the tile calls they launched."""
         out: Dict[str, Any] = {"submits": self.submit_count,
-                               "deduped_requests": self.deduped_requests}
+                               "deduped_requests": self.deduped_requests,
+                               "align_walks": self.align_walks,
+                               "align_tiles": self.align_tiles}
         out.update({f"requests.{k}": int(v)
                     for k, v in sorted(self.request_counts.items())})
         return out

@@ -377,34 +377,37 @@ def _merge_paged(dense, paged, rows, block_size):
     per key — cache_slots for global-attention layers, the ring length
     for sliding-window layers — and each key's trash floor is recovered
     from its flat pool's shape (the trash block is the last
-    ``block_size`` rows)."""
+    ``block_size`` rows). Its ops carry the ``state_gather`` scope."""
     from repro.models import attention  # local: avoid import cycle
 
     caches = {}
-    for key, entry in dense.items():
-        if key in paged:
-            entry = dict(entry)
-            entry["attn"] = attention.paged_view(
-                paged[key], rows[key],
-                attention.paged_live_rows(paged[key], block_size))
-        caches[key] = entry
+    with jax.named_scope("state_gather"):
+        for key, entry in dense.items():
+            if key in paged:
+                entry = dict(entry)
+                entry["attn"] = attention.paged_view(
+                    paged[key], rows[key],
+                    attention.paged_live_rows(paged[key], block_size))
+            caches[key] = entry
     return caches
 
 
 def _split_paged(caches, paged, rows):
     """Inverse of _merge_paged: scatter updated views back into the pool
-    and strip them from the dense tree (None placeholders restored)."""
+    and strip them from the dense tree (None placeholders restored).
+    Its ops carry the ``state_scatter`` scope."""
     from repro.models import attention
 
     dense, paged_new = {}, {}
-    for key, entry in caches.items():
-        if key in paged:
-            entry = dict(entry)
-            view = entry["attn"]
-            entry["attn"] = None
-            paged_new[key] = attention.paged_writeback(paged[key], view,
-                                                       rows[key])
-        dense[key] = entry
+    with jax.named_scope("state_scatter"):
+        for key, entry in caches.items():
+            if key in paged:
+                entry = dict(entry)
+                view = entry["attn"]
+                entry["attn"] = None
+                paged_new[key] = attention.paged_writeback(
+                    paged[key], view, rows[key])
+            dense[key] = entry
     return dense, paged_new
 
 
@@ -446,18 +449,22 @@ def jit_paged_chunk_step(cfg: ModelConfig):
     per-sub-row (len(idx), V_key). Dense leaves gather/scatter on the
     slot axis, paged leaves through their page tables. Logits cover every
     chunk position of every sub-row (prompt scoring reads them; plain
-    prefill ignores them).
+    prefill ignores them). The dense state's gather and scatter carry the
+    ``state_gather`` / ``state_scatter`` scopes.
     """
     step = make_chunk_step(cfg)
 
     def run(params, dense, paged, idx, rows, tokens, pos, block_size: int):
-        sub = jax.tree_util.tree_map(
-            lambda l: jnp.take(l, idx, axis=1), dense)
+        with jax.named_scope("state_gather"):
+            sub = jax.tree_util.tree_map(
+                lambda l: jnp.take(l, idx, axis=1), dense)
         caches = _merge_paged(sub, paged, rows, block_size)
         logits, caches = step(params, caches, tokens, pos)
         sub, paged = _split_paged(caches, paged, rows)
-        dense = jax.tree_util.tree_map(
-            lambda l, s: l.at[:, idx].set(s.astype(l.dtype)), dense, sub)
+        with jax.named_scope("state_scatter"):
+            dense = jax.tree_util.tree_map(
+                lambda l, s: l.at[:, idx].set(s.astype(l.dtype)), dense,
+                sub)
         return logits, dense, paged
 
     return obs_trace.instrumented_jit(

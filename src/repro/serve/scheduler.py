@@ -701,7 +701,8 @@ class Scheduler:
         """One tick: admit, chunk-prefill, one fused decode, retire.
         Returns every completion not yet handed out — including requests
         finished at submit time by the request cache."""
-        self._admit()
+        with self.tracer.span("admit", "scheduler"):
+            self._admit()
         self._prefill_chunks()
         self._decode_once()
         self.counters["steps"] += 1
