@@ -62,8 +62,7 @@ def mapper_control(driver, window) -> dict:
     exact = ref_mapper.reference_for(system.genome, system.config)
     low = ref_mapper.reference_for(system.genome, system.config, "bfloat16")
     reads = [it["read"] for it in window.items]
-    return ref_mapper.compare([low.map(r) for r in reads],
-                              [exact.map(r) for r in reads],
+    return ref_mapper.compare(low.map_all(reads), exact.map_all(reads),
                               [it["start"] for it in window.items],
                               system.config["accuracy_tolerance"])
 

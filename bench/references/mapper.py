@@ -22,6 +22,10 @@ it (minimap2's skeleton as the Squire paper uses it):
          matrix is swept by anti-diagonals in exact integers (the
          configuration's float32 holds every score exactly).
 
+``map`` does one read on the host. ``map_all`` seeds and chains each read
+on the host the same way, then aligns all of them together: one jitted
+anti-diagonal sweep on the device, batched over reads (``sw_all``).
+
 ``dtype`` takes the control's precision: bfloat16 rounds every match-up
 score, chain score and SW cell.
 """
@@ -29,7 +33,8 @@ score, chain score and SW cell.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional
+import functools
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +42,11 @@ import ml_dtypes
 import numpy as np
 
 NEG = np.float32(-1e18)
+# Padding codes of the batched SW sweep: a read is padded with one, its
+# window with the other; neither matches a base (0-3) or the other.
+PAD_READ, PAD_WINDOW = 254, 255
+SW_GRID = 512          # padded read and window lengths are multiples
+SW_MAX_BATCH = 512     # reads per call of the sweep
 
 
 @dataclasses.dataclass
@@ -110,10 +120,7 @@ class Reference:
         c = self.cfg
         band, kmer = c["band"], c["k"]
         n = len(q)
-        s = np.asarray(_matchup_scores(jnp.asarray(q, jnp.int32),
-                                       jnp.asarray(r, jnp.int32), band,
-                                       kmer, jnp.dtype(self.dtype)))
-        s = s.astype(self.dtype)
+        s = _matchup_scores(q, r, band, kmer).astype(self.dtype)
         f = np.zeros(n, self.dtype)
         pred = np.full(n, -1, np.int64)
         ring = np.full(band, NEG, self.dtype)   # ring[t-1] = f(i - t)
@@ -177,21 +184,67 @@ class Reference:
             prev2, prev = prev, cur
         return float(best)
 
+    def sw_all(self, pairs: Sequence[Tuple[np.ndarray, np.ndarray]]
+               ) -> List[float]:
+        """``sw`` of every (read, window) pair, swept on the device.
+
+        Pairs are grouped by their lengths padded to ``SW_GRID``, and each
+        group goes through ``_sw_sweep`` in chunks of at most
+        ``SW_MAX_BATCH`` pairs, the last chunk padded to a power of two
+        (at least 8) with empty pairs: a few programs, and bounded memory.
+        A read is padded with ``PAD_READ`` and its window with
+        ``PAD_WINDOW``, which match nothing. That leaves the score as it
+        is: the true cells depend only on cells above and left of them,
+        all true; a padded cell takes a mismatch or a gap from one of its
+        neighbours, so it lies below the largest true cell it derives
+        from, and the floor 0 is reached by true cells too. The sweep
+        keeps the anti-diagonal form, so under bfloat16 every value it
+        rounds is a cell, as in ``sw``.
+        """
+        c = self.cfg
+        exact = self.dtype == np.float32
+        dt = jnp.int32 if exact else jnp.dtype(self.dtype)
+        cast = int if exact else float
+        scoring = dict(match=cast(c["sw_match"]),
+                       mismatch=cast(c["sw_mismatch"]),
+                       gap=cast(c["sw_gap"]), dtype=dt)
+        groups = {}
+        for k, (a, b) in enumerate(pairs):
+            key = (_grid(len(a)), _grid(len(b)))
+            groups.setdefault(key, []).append(k)
+        out = [0.0] * len(pairs)
+        for (n, m), ks in sorted(groups.items()):
+            for s in range(0, len(ks), SW_MAX_BATCH):
+                chunk = ks[s:s + SW_MAX_BATCH]
+                rows = max(8, 1 << (len(chunk) - 1).bit_length())
+                a = np.full((rows, n), PAD_READ, np.int32)
+                b = np.full((rows, m), PAD_WINDOW, np.int32)
+                for r, k in enumerate(chunk):
+                    a[r, :len(pairs[k][0])] = pairs[k][0]
+                    b[r, :len(pairs[k][1])] = pairs[k][1]
+                best = np.asarray(_sw_sweep(a, b, **scoring))
+                for r, k in enumerate(chunk):
+                    out[k] = float(best[r])
+        return out
+
     # -- a read ----------------------------------------------------------
 
-    def map(self, read: np.ndarray) -> Mapping:
+    def locate(self, read: np.ndarray):
+        """Seed and chain ``read``: (mapping without its SW score, the
+        genome window (lo, hi) to align it against, or None where the
+        read maps nowhere and the mapping is final)."""
         c = self.cfg
         read = np.asarray(read)
         if len(read) < c["k"] + c["w"]:
-            return Mapping(-1, 0.0, 0.0, 0)
+            return Mapping(-1, 0.0, 0.0, 0), None
         q, r = self.anchors(read)
         nv = len(q)
         if nv < 2:
-            return Mapping(-1, 0.0, 0.0, nv)
+            return Mapping(-1, 0.0, 0.0, nv), None
         f, pred = self.chain(q, r)
         chains = self.backtrack(f, pred, c["min_chain_score"])
         if not chains:
-            return Mapping(-1, 0.0, 0.0, nv)
+            return Mapping(-1, 0.0, 0.0, nv), None
         score, members = chains[0]
         first, last = members[0], members[-1]
         pad = c["sw_window_pad"]
@@ -199,8 +252,69 @@ class Reference:
         hi = min(len(self.genome),
                  int(r[last]) + (len(read) - int(q[last])) + pad)
         if hi - lo < c["k"]:
-            return Mapping(-1, 0.0, score, nv)
-        return Mapping(lo, self.sw(read, self.genome[lo:hi]), score, nv)
+            return Mapping(-1, 0.0, score, nv), None
+        return Mapping(lo, 0.0, score, nv), (lo, hi)
+
+    def map(self, read: np.ndarray) -> Mapping:
+        out, window = self.locate(read)
+        if window is not None:
+            out.sw_score = self.sw(np.asarray(read),
+                                   self.genome[window[0]:window[1]])
+        return out
+
+    def map_all(self, reads: Sequence[np.ndarray]) -> List[Mapping]:
+        """``[self.map(r) for r in reads]``, with the alignments of all
+        reads swept together on the device."""
+        located = [self.locate(r) for r in reads]
+        todo = [k for k, (_, w) in enumerate(located) if w is not None]
+        scores = self.sw_all([(np.asarray(reads[k]),
+                               self.genome[slice(*located[k][1])])
+                              for k in todo])
+        for k, score in zip(todo, scores):
+            located[k][0].sw_score = score
+        return [m for m, _ in located]
+
+
+def _grid(n: int) -> int:
+    return -(-max(n, 1) // SW_GRID) * SW_GRID
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("match", "mismatch", "gap", "dtype"))
+def _sw_sweep(a, b, *, match, mismatch, gap, dtype):
+    """(B,) matrix maxima of the Smith-Waterman matrices of reads ``a``
+    (B, N) against windows ``b`` (B, M), swept by anti-diagonals d = i + j
+    with every value of ``dtype``. A diagonal is held by its row index i
+    in 0..N; cells off the matrix (j < 1 or j > M) and row 0 are 0."""
+    rows_n, n = a.shape
+    m = b.shape[1]
+    # rev[:, M + N + 1 - d + i] = b[:, d - 1 - i], PAD_WINDOW off the window
+    edge = jnp.full((rows_n, n + 1), PAD_WINDOW, b.dtype)
+    rev = jnp.concatenate([edge, b, edge], axis=1)[:, ::-1]
+    a_i = jnp.concatenate(
+        [jnp.full((rows_n, 1), PAD_READ, a.dtype), a], axis=1)
+    i = jnp.arange(n + 1)
+    zero = jnp.zeros((rows_n, n + 1), dtype)
+    match, mismatch, gap = (jnp.asarray(v, dtype)
+                            for v in (match, mismatch, gap))
+
+    def shift(x):                     # x[:, i - 1], 0 at i = 0
+        return jnp.concatenate([zero[:, :1], x[:, :-1]], axis=1)
+
+    def diagonal(d, carry):
+        prev2, prev, best = carry     # diagonals d - 2 and d - 1
+        b_j = jax.lax.dynamic_slice_in_dim(rev, m + n + 1 - d, n + 1, axis=1)
+        sub = jnp.where(a_i == b_j, match, mismatch)
+        h = jnp.maximum(jnp.maximum(shift(prev2) + sub, shift(prev) - gap),
+                        prev - gap)
+        j = d - i
+        on = (i >= 1) & (j >= 1) & (j <= m)
+        h = jnp.where(on, jnp.maximum(h, zero), zero)
+        return prev, h, jnp.maximum(best, h)
+
+    _, _, best = jax.lax.fori_loop(2, n + m + 1, diagonal,
+                                   (zero, zero, zero))
+    return best.max(axis=1)
 
 
 @jax.jit
@@ -219,18 +333,19 @@ def _scores_f32(q, r, band_arange, kmer):
     return jnp.where(ok, alpha - beta, NEG)
 
 
-def _matchup_scores(q, r, band: int, kmer: int, dtype):
+def _matchup_scores(q: np.ndarray, r: np.ndarray, band: int, kmer: int
+                    ) -> np.ndarray:
     """(n, band) float32 scores S[i, t] of chaining anchor i after
     anchor i - t (t = 1..band); -1e18 where not allowed. Anchors are
-    padded to a multiple of 512 so that few shapes compile."""
-    n = q.shape[0]
+    padded on the host to a multiple of 512, so that one program serves
+    every read of a padded size."""
+    n = len(q)
     npad = -(-max(n, 1) // 512) * 512
-    qp = jnp.concatenate([q, jnp.zeros(npad - n, jnp.int32)])
-    rp = jnp.concatenate([r, jnp.full(npad - n, 2**30, jnp.int32)])
-    s = _scores_f32(qp, rp, jnp.arange(1, band + 1), kmer)[:n]
-    if dtype != jnp.float32:
-        s = s.astype(dtype)
-    return s
+    qp = np.zeros(npad, np.int32)
+    rp = np.full(npad, 2**30, np.int32)
+    qp[:n], rp[:n] = q, r
+    s = _scores_f32(qp, rp, np.arange(1, band + 1, dtype=np.int32), kmer)
+    return np.asarray(s)[:n]
 
 
 def control_dtype(name: str):

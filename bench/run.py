@@ -12,7 +12,8 @@ A run: refuses anything but a TPU with the chips the cell asks for; sets
 up the system and warms every shape its traffic uses (``setup_s`` runs
 from process start to the window's start); measures for ``--seconds``;
 reads the device's peak memory; frees the program's state; checks what
-the window produced against the plain reference; prints each compared
+the window produced against the plain reference (its seconds are the
+line's ``check_s``, a field and not a metric); prints each compared
 number beside its limit on standard error, and last on standard output
 one JSON line. With ``--trace 1`` the window is traced by the JAX
 profiler and the line carries the cell's per-layer metrics instead of
@@ -185,21 +186,22 @@ def execute(cell: str, seed: int, seconds: float, trace: bool, *,
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
 
-    t = time.perf_counter()
+    t, built_before_check = time.perf_counter(), PROGRAMS["built"]
     numbers = driver.check(window)
     limits = config["limits"]
     checks = {k: {"value": numbers[k], "limit": limits[k]} for k in limits}
     correct = (window.failed == 0
                and all(c["value"] <= c["limit"] for c in checks.values()))
-    log(f"reference check {time.perf_counter() - t!r} s over "
-        f"{len(window.items)} finished")
+    check_s = time.perf_counter() - t
+    log(f"reference check {check_s!r} s over {len(window.items)} finished, "
+        f"{PROGRAMS['built'] - built_before_check} programs built or loaded")
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(devices), "memory_peak_bytes": memory}
     out = {"correct": bool(correct), "attempted": int(window.attempted),
            "failed": int(window.failed), "metrics": metrics,
            "device": device, "programs_built_after_setup":
-           window.info["programs_built_after_setup"]}
+           window.info["programs_built_after_setup"], "check_s": check_s}
     if tr is not None:
         device["busy_s"] = tr.busy_s()
         device["window_s"] = tr.window_s

@@ -4,7 +4,8 @@ requests against a genome the benchmark generates from the seed.
 Set-up makes the genome on the host and builds the program's minimizer
 index on the device. ``map`` is the timed entry: one bulk submit of a
 batch of reads. ``check`` maps every read the window completed with the
-plain reference (``bench/references/mapper.py``) and compares.
+plain reference (``bench/references/mapper.py``), its alignments batched
+on the device once the program's state is freed, and compares.
 """
 
 from __future__ import annotations
@@ -128,9 +129,10 @@ class System:
 
     def check(self, items: List[dict], seed: int, control: str = "float32"
               ) -> dict:
-        """Compare every completed read with the reference."""
+        """Compare every completed read with the reference, which aligns
+        them all together on the device (``Reference.map_all``)."""
         ref = ref_mapper.reference_for(self.genome, self.config, control)
-        want = [ref.map(it["read"]) for it in items]
+        want = ref.map_all([it["read"] for it in items])
         got = [it["result"] for it in items]
         return ref_mapper.compare(got, want, [it["start"] for it in items],
                                   self.config["accuracy_tolerance"])

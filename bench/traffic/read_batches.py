@@ -35,13 +35,18 @@ from bench.systems.mapper import System, sequence_read, shifted_read
 PROBE_ACCURACY = 0.95
 
 
+def length_cycle(config: dict, mix: dict, seed: int):
+    """(per_quartile, 4) read lengths of the batches of one cycle."""
+    mean, sd = config["read_mean"], config["read_sd"]
+    return gen.quartile_cycle(
+        mean, sd, mix["clip_lo"], mean + mix["clip_hi_sd"] * sd,
+        mix["per_quartile"], seed, "lengths")
+
+
 class Driver:
     def __init__(self, config: dict, mix: dict, seed: int, seconds: float):
         self.mix, self.seed, self.seconds = mix, seed, seconds
-        mean, sd = config["read_mean"], config["read_sd"]
-        self.cycle = gen.quartile_cycle(
-            mean, sd, mix["clip_lo"], mean + mix["clip_hi_sd"] * sd,
-            mix["per_quartile"], seed, "lengths")
+        self.cycle = length_cycle(config, mix, seed)
         self.system = System(config, seed)
         self.accuracy = config["read_accuracy"]
         self.error_mix = config["error_mix"]
